@@ -476,6 +476,8 @@ def main(argv=None):
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "BENCH_kernels.json"))
     args = p.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     # grouped-state comparison first: it is the most noise-sensitive and
     # deserves the freshest process state (interpret-mode Pallas runs in
     # bench_ops leave the allocator in a different regime)

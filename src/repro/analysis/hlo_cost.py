@@ -322,14 +322,3 @@ def analyze_file(path: str) -> dict:
     with open(path) as f:
         return analyze(f.read())
 
-
-def xla_cost(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized across jax versions.
-
-    jax <= 0.4.x returns a one-element list of dicts (one per program);
-    newer jax returns the dict directly.  Always returns a dict.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)

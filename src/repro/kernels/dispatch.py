@@ -8,20 +8,23 @@ jnp expressions itself.  Per call the dispatcher picks a route:
   * ``pallas`` — the fused Pallas kernel, with automatic pad-to-tile for
     ragged operands (lane = 128, sublane = 8/16): inputs are zero-padded up
     to block multiples and outputs sliced back, so the old hard
-    ``assert K % bk == 0`` never bites callers.  On non-TPU backends the
-    kernels run in interpret mode (see kernels/ops.py / the
-    REPRO_PALLAS_INTERPRET knob).
+    ``assert K % bk == 0`` never bites callers.  Off the TPU the kernels
+    run in interpret mode (kernels/ops.py ``_interpret``).
   * ``xla`` — the pure-jnp reference path (kernels/ref.py-style expressions
     with fp32 accumulation), which XLA fuses well on CPU/GPU and which
-    serves as the fallback when a Pallas kernel's VMEM working set would
-    blow the ~16 MB budget.
+    serves as the fallback when a Pallas kernel's VMEM working set
+    (double-buffered blocks counted twice) would blow the 12 MiB budget
+    under the chip's 16 MiB scoped-VMEM limit.
 
 Route selection: ``REPRO_KERNEL_DISPATCH`` ∈ {pallas, xla, auto} overrides;
-``auto`` (default) = Pallas on TPU when the shape guard passes, XLA
-otherwise.  The VMEM guard uses each operand's REAL itemsize — a bf16
-workload has half the working set of the same-shape fp32 one and must not
-be spuriously routed to the XLA fallback.  ``TABLE`` maps
-op -> {route -> impl} and is deliberately a plain dict so tests can
+``auto`` (default) = Pallas on TPU when the shape guard passes and no
+multi-device mesh is active (Mosaic kernels cannot be partitioned by the
+compiler), XLA otherwise.  :func:`route_log` records every choice.  The
+VMEM guard uses each operand's REAL itemsize — a bf16 workload has half
+the working set of the same-shape fp32 one and must not be spuriously
+routed to the XLA fallback.  A fused backward whose whole dB would not
+fit is split over N (``_bwd_chunks``) before it falls back.  ``TABLE``
+maps op -> {route -> impl} and is deliberately a plain dict so tests can
 monkeypatch impls to assert the hot path really flows through here.
 
 Mixed-precision contract (mirrored by kernels/ref.py):
@@ -127,31 +130,67 @@ def _sizes(dtypes: Sequence, n: int, itemsize: int) -> Tuple[float, ...]:
     return (float(itemsize),) * n
 
 
-def _bwd_vmem_bytes(M: int, K: int, N: int, r: int, sizes) -> int:
+def _bwd_vmem_bytes(M: int, K: int, N: int, r: int, sizes,
+                    n_chunks: int = 1) -> int:
     """Working set of the fused backward (see lowrank_backward.py).
 
-    Per-operand itemsizes: (dy, w, v, b, p) — dx rides dy's dtype, the dx
-    accumulator and the whole dB stay fp32 in VMEM.
+    Per-operand itemsizes: (dy, w, v, b, p) — dx rides dy's dtype (fp32
+    partials when N is split into ``n_chunks``), the dx accumulator and
+    the resident dB chunk stay fp32 in VMEM.  Every pipelined block, the
+    outputs included, is double-buffered; only the scratch is single.
     """
     sdy, sw, sv, sb, sp = sizes
     bm, Mp, bn, Np, _, Kp = _blocks(M, N, K)
-    return (Kp * bn * sw + Kp * r * sv      # w column strip + v
-            + 4 * (bm * Kp + Np * r)        # dx f32 accumulator + whole dB
-            + bm * Kp * sdy                 # dx output block (dy.dtype)
-            + bm * bn * sdy + bn * r * sb + bm * r * sp)  # dy/b/p tiles
+    nc = _round_up(-(-Np // n_chunks), bn)     # dB rows resident at once
+    sdx = sdy if n_chunks == 1 else 4
+    blocks = (Kp * bn * sw + Kp * r * sv    # w column strip + v
+              + bm * bn * sdy + bn * r * sb + bm * r * sp  # dy/b/p tiles
+              + bm * Kp * sdx               # dx output block
+              + 4 * nc * r)                 # dB chunk (fp32)
+    return 2 * blocks + 4 * bm * Kp         # + dx f32 accumulator
+
+
+def _bwd_chunks(M: int, K: int, N: int, r: int, sizes) -> Optional[int]:
+    """Fewest N-chunks whose fused-backward working set fits
+    ``VMEM_BUDGET``; None when not even one lane-wide chunk fits."""
+    n_tiles = _blocks(M, N, K)[3] // LANE if N > LANE else 1
+    for c in range(1, n_tiles + 1):
+        if _bwd_vmem_bytes(M, K, N, r, sizes, c) <= VMEM_BUDGET:
+            return c
+    return None
 
 
 def _fwd_vmem_bytes(M: int, K: int, N: int, r: int, sizes) -> int:
-    """Per-operand itemsizes: (x, w, v, b) — y/p accumulators are fp32."""
+    """Per-operand itemsizes: (x, w, v, b) — y/p accumulators are fp32.
+    Pipelined blocks are double-buffered, the scratch is single."""
     sx, sw, sv, sb = sizes
     bm, _, bn, _, bk, _ = _blocks(M, N, K)
-    return (bm * bk * sx + bk * bn * sw + bk * r * sv + bn * r * sb
-            + bm * bn * sx                  # y output tile (x.dtype)
-            + 4 * (bm * bn + bm * r))       # f32 acc + accp scratch
+    blocks = (bm * bk * sx + bk * bn * sw + bk * r * sv + bn * r * sb
+              + bm * bn * sx)               # + y output tile (x.dtype)
+    return 2 * blocks + 4 * (bm * bn + bm * r)   # + f32 acc/accp scratch
+
+
+_ROUTES: dict = {}
 
 
 def route(op: str, *, shapes: Tuple[int, ...] = (),
           dtypes: Sequence = (), itemsize: int = 4) -> str:
+    """Pick 'pallas' or 'xla' for ``op`` and record the choice in
+    :func:`route_log` (keyed by ``(op, shapes)``)."""
+    rt = _route(op, shapes, dtypes, itemsize)
+    _ROUTES[(op, tuple(shapes))] = rt
+    return rt
+
+
+def route_log() -> dict:
+    """``{(op, shapes): route}`` for every routed call since the last
+    :func:`clear_kernel_cache` — which ops took the kernel and which the
+    XLA fallback (decided at trace time)."""
+    return dict(_ROUTES)
+
+
+def _route(op: str, shapes: Tuple[int, ...], dtypes: Sequence,
+           itemsize: int) -> str:
     """Pick 'pallas' or 'xla' for ``op`` given (M, K, N, r)-style shapes.
 
     ``dtypes``: the op's operand dtypes in call order — the VMEM guard
@@ -168,6 +207,13 @@ def route(op: str, *, shapes: Tuple[int, ...] = (),
             f"REPRO_KERNEL_DISPATCH={env!r}: expected pallas, xla or auto")
     if jax.default_backend() != "tpu":
         return "xla"        # interpret-mode Pallas is a debug tool, not a path
+    from ..sharding import ctx   # deferred: sharding imports optim
+    mesh = ctx.get_mesh()
+    if mesh is not None and mesh.size > 1:
+        # the compiler cannot partition a Mosaic kernel across devices: a
+        # kernel inside a sharded program needs a shard_map, which these
+        # ops do not have yet — on a mesh every op takes the XLA schedule
+        return "xla"
     if op == "lowrank_forward" and shapes:
         m, k, n, r = shapes
         sz = _sizes(dtypes, 4, itemsize)
@@ -175,8 +221,7 @@ def route(op: str, *, shapes: Tuple[int, ...] = (),
             return "xla"
     if op == "lowrank_backward" and shapes:
         m, k, n, r = shapes
-        sz = _sizes(dtypes, 5, itemsize)
-        if _bwd_vmem_bytes(m, k, n, r, sz) > VMEM_BUDGET:
+        if _bwd_chunks(m, k, n, r, _sizes(dtypes, 5, itemsize)) is None:
             return "xla"
     if op == "lowrank_batch_forward" and shapes:
         m, k, n, r = shapes   # m = per-row tokens (seq), not batch*seq
@@ -225,6 +270,7 @@ def kernel_cache_info() -> dict:
 
 def clear_kernel_cache() -> None:
     _KERNEL_CACHE.clear()
+    _ROUTES.clear()
     _CACHE_STATS.update(hits=0, misses=0)
 
 
@@ -313,13 +359,20 @@ def _pallas_forward(x2: Array, w: Array, v: Array, b: Array,
 def _pallas_backward(dy2: Array, w: Array, v: Array, b: Array, p2: Array):
     M, N = dy2.shape
     K, r = w.shape[0], v.shape[1]
-    bm, Mp, bn, Np, _, Kp = _blocks(M, N, K)
+    bm, Mp, bn, _, _, Kp = _blocks(M, N, K)
+    sizes = tuple(_itemsize(a.dtype) for a in (dy2, w, v, b, p2))
+    # the forced-pallas route (interpret mode off the chip) may reach a
+    # shape the guard refuses: split N as finely as the lanes allow
+    chunks = _bwd_chunks(M, K, N, r, sizes) or max(1, -(-N // LANE))
+    Np = _round_up(N, chunks * bn)
     itp = _interpret()
     fn = _cached_kernel(
         "lowrank_backward",
-        ((Mp, Kp, Np, r), _dt_names(dy2, w, v, b, p2), (bm, bn), itp),
+        ((Mp, Kp, Np, r), _dt_names(dy2, w, v, b, p2), (bm, bn), chunks,
+         itp),
         lambda: (lambda dyp, wp, vp, bp, pp: _pl_backward(
-            dyp, wp, vp, bp, pp, bm=bm, bn=bn, interpret=itp)))
+            dyp, wp, vp, bp, pp, bm=bm, bn=bn, n_chunks=chunks,
+            interpret=itp)))
     dx, db = fn(_pad2(dy2, Mp, Np), _pad2(w, Kp, Np), _pad2(v, Kp, r),
                 _pad2(b, Np, r), _pad2(p2, Mp, r))
     return dx[:M, :K], db[:N]

@@ -14,11 +14,16 @@ VMEM, so each (bm, bn) dy tile is read exactly once and contributes
   * its i-contribution to dB rows j              (dy^T p_i).
 
 dx accumulates in a (bm, K) f32 scratch written at the end of each i row;
-dB lives in VMEM as a whole-array output (constant index map -> single
-writeback at kernel end) because its contraction dim (M) is the OUTER grid
-axis.  VMEM cost is therefore ~ K*(bn+r)*s + 4*(bm*K + N*r) bytes — the
-dispatch layer guards this against the ~16 MB budget and falls back to the
-XLA path for oversized operands.
+dB lives in VMEM as a resident output block because its contraction dim
+(M) is the OUTER grid axis.  A wide N (an unembedding) would not fit whole,
+so the grid carries a leading chunk axis: grid (C, M/bm, N/(C*bn)), and
+chunk c owns dB rows [c*N/C, (c+1)*N/C) — resident for the whole i sweep
+and written back once when c advances.  Each chunk then yields a partial
+dx over its own N columns; those are emitted fp32 as (C, M, K) and summed
+outside the kernel (C = 1 writes dx straight in dy.dtype).  VMEM cost is
+therefore ~ K*(bn+r)*s + 4*(bm*K + (N/C)*r) bytes — the dispatch layer
+picks C so it fits the budget and falls back to the XLA path when even
+one bn-wide chunk does not.
 """
 from __future__ import annotations
 
@@ -36,8 +41,8 @@ Array = jax.Array
 
 def _kernel(dy_ref, w_ref, v_ref, b_ref, p_ref, dx_ref, db_ref, acc_ref, *,
             n_j: int, bn: int):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
+    i = pl.program_id(1)
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init_acc():
@@ -62,38 +67,45 @@ def _kernel(dy_ref, w_ref, v_ref, b_ref, p_ref, dx_ref, db_ref, acc_ref, *,
 
 
 def lowrank_backward(dy: Array, w: Array, v: Array, b: Array, p: Array, *,
-                     bm: int = 128, bn: int = 128,
+                     bm: int = 128, bn: int = 128, n_chunks: int = 1,
                      interpret: bool = False):
     """dy (M,N), w (K,N), v (K,r), b (N,r), p (M,r) -> (dx (M,K), db (N,r)).
 
-    db is fp32 (Adam consumes it in fp32); dx is dy.dtype.
+    db is fp32 (Adam consumes it in fp32); dx is dy.dtype.  ``n_chunks``
+    splits N so that only N/n_chunks rows of dB are resident at a time.
     """
     M, N = dy.shape
     K = w.shape[0]
     r = v.shape[1]
-    bm, bn = min(bm, M), min(bn, N)
-    assert M % bm == 0 and N % bn == 0, (M, N, bm, bn)
-    n_j = N // bn
+    bm, bn = min(bm, M), min(bn, N // n_chunks)
+    assert M % bm == 0 and N % (n_chunks * bn) == 0, (M, N, bm, bn,
+                                                       n_chunks)
+    n_j = N // (n_chunks * bn)
+    nc = N // n_chunks
+    dx_dtype = dy.dtype if n_chunks == 1 else jnp.float32
 
-    grid = (M // bm, n_j)
-    return pl.pallas_call(
+    grid = (n_chunks, M // bm, n_j)
+    dx, db = pl.pallas_call(
         functools.partial(_kernel, n_j=n_j, bn=bn),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((K, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((K, r), lambda i, j: (0, 0)),
-            pl.BlockSpec((bn, r), lambda i, j: (j, 0)),
-            pl.BlockSpec((bm, r), lambda i, j: (i, 0)),
+            pl.BlockSpec((bm, bn), lambda c, i, j: (i, c * n_j + j)),
+            pl.BlockSpec((K, bn), lambda c, i, j: (0, c * n_j + j)),
+            pl.BlockSpec((K, r), lambda c, i, j: (0, 0)),
+            pl.BlockSpec((bn, r), lambda c, i, j: (c * n_j + j, 0)),
+            pl.BlockSpec((bm, r), lambda c, i, j: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bm, K), lambda i, j: (i, 0)),
-            pl.BlockSpec((N, r), lambda i, j: (0, 0)),
+            pl.BlockSpec((None, bm, K), lambda c, i, j: (c, i, 0)),
+            pl.BlockSpec((nc, r), lambda c, i, j: (c, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((M, K), dy.dtype),
+            jax.ShapeDtypeStruct((n_chunks, M, K), dx_dtype),
             jax.ShapeDtypeStruct((N, r), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bm, K), jnp.float32)],
         interpret=interpret,
     )(dy, w, v, b, p)
+    if n_chunks == 1:
+        return dx[0], db
+    return jnp.sum(dx, axis=0).astype(dy.dtype), db

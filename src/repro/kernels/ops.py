@@ -1,9 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels run in ``interpret=True`` mode — the kernel
-body executes eagerly in Python, validating BlockSpec indexing and numerics
-against :mod:`ref`.  On TPU (``jax.default_backend() in {'tpu'}``) they
-compile to Mosaic.  ``interpret`` can be forced via REPRO_PALLAS_INTERPRET.
+Off the TPU kernels run in ``interpret=True`` mode — the kernel body
+executes eagerly in Python, validating BlockSpec indexing and numerics
+against :mod:`ref`.  On a TPU they always compile to Mosaic: interpret mode
+is decided by the backend alone, so a chip run can never time the
+interpreter.
 
 These wrappers are the raw aligned-shape entry points (benchmarks, tests);
 the training hot path goes through :mod:`repro.kernels.dispatch`, which
@@ -15,7 +16,6 @@ in fp32 — see the casting contract in :mod:`repro.kernels.ref`.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 
@@ -30,9 +30,6 @@ from .subspace_adam import subspace_lion_q8 as _lion_q8
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "")
     return jax.default_backend() != "tpu"
 
 

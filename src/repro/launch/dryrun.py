@@ -1,4 +1,5 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512")
 
@@ -6,8 +7,11 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
 cell on the production mesh and record memory/cost/collective analysis.
 
 MUST be executed as a module (``python -m repro.launch.dryrun``) in a fresh
-process — the two lines above run before any jax import so the 512
-placeholder host devices exist before jax locks the device count.
+process — the lines above run before any jax import so the 512
+placeholder host devices exist before jax locks the device count.  It is a
+CPU-only analysis tool: the platform is pinned to the CPU so that on a
+machine with a chip the production mesh is still built from placeholder
+host devices.
 
 Usage:
   python -m repro.launch.dryrun                      # all cells, (16,16)
@@ -57,7 +61,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, optimizer: str,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = hlo_cost.xla_cost(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     # loop-aware per-device cost (XLA's cost_analysis counts while bodies
     # once; ours multiplies by known_trip_count — see analysis/hlo_cost.py)

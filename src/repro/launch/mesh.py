@@ -11,17 +11,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` landed after
-    0.4.37 (where every axis is implicitly Auto), so pass it only when the
-    installed jax understands it."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,9 +1,11 @@
 """Training launcher.
 
-Single-host CPU (this container): runs real steps on reduced/paper configs.
-Multi-host TPU: call ``jax.distributed.initialize()`` (env-driven), build
-the production mesh, and jit the same step functions with the sharding
-rules from :mod:`repro.sharding` — the exact lowering the dry-run proves.
+Runs the :class:`~repro.train.trainer.Trainer` on one device — the CPU
+or one chip — on a reduced or paper config.  It builds no mesh: the
+sharded lowering is proven by ``python -m repro.launch.dryrun`` and run on
+four chips by ``python chip_smoke.py --four-chips``.  With
+``REPRO_DISTRIBUTED`` set it calls ``jax.distributed.initialize()`` first
+(env-driven multi-host start).
 
 Examples:
   python -m repro.launch.train --arch llama-tiny --steps 200
@@ -45,6 +47,8 @@ def main(argv=None):
     p.add_argument("--min-dim-lowrank", type=int, default=128)
     args = p.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if os.environ.get("REPRO_DISTRIBUTED"):  # multi-host entry (TPU pods)
         import jax
         jax.distributed.initialize()

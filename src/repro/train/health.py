@@ -61,22 +61,29 @@ class HealthState(NamedTuple):
     seen: Array          # i32 total guard steps (accepted + skipped)
 
 
+def _f32(v=0.0) -> Array:
+    return jnp.full((), v, jnp.float32)
+
+
+def _i32(v=0) -> Array:
+    return jnp.full((), v, jnp.int32)
+
+
+# Every field gets its own buffer: the Trainer donates this state, and a
+# donating call refuses one buffer passed as two arguments.
+
 def init_health() -> HealthState:
-    z32 = jnp.zeros((), jnp.float32)
-    i32 = jnp.zeros((), jnp.int32)
-    return HealthState(ema_mean=z32, ema_var=z32, good_steps=i32,
-                       consec_skips=i32, total_skips=i32,
-                       last_anomaly=jnp.full((), -1, jnp.int32), seen=i32)
+    return HealthState(ema_mean=_f32(), ema_var=_f32(), good_steps=_i32(),
+                       consec_skips=_i32(), total_skips=_i32(),
+                       last_anomaly=_i32(-1), seen=_i32())
 
 
 def after_rollback(h: HealthState) -> HealthState:
     """Re-arm after a restore+backoff: the spike detector's statistics
     belong to the old LR/projection, so reset EMA and the warmup gate;
     lifetime counters (total skips, last anomaly, steps seen) persist."""
-    z32 = jnp.zeros((), jnp.float32)
-    i32 = jnp.zeros((), jnp.int32)
-    return h._replace(ema_mean=z32, ema_var=z32, good_steps=i32,
-                      consec_skips=i32)
+    return h._replace(ema_mean=_f32(), ema_var=_f32(), good_steps=_i32(),
+                      consec_skips=_i32())
 
 
 def _is_step(idx: Array, steps) -> Array:

@@ -106,6 +106,7 @@ class Trainer:
         self.health = health.init_health()
         self.rollbacks = 0                 # lifetime (carried via manifest)
         self.total_skips_offset = 0        # skips from previous runs
+        self._shardings = None  # (params, opt, health) once shard() ran
         self._build_steps()
         self.step = 0
 
@@ -130,11 +131,49 @@ class Trainer:
             donate = (0, 1, 2) if not on_cpu else ()
         else:
             donate = (0, 1) if not on_cpu else ()
-        self._inner = jax.jit(inner, donate_argnums=donate)
+        # after shard(): the carried state keeps its placement from step to
+        # step, so no step sees new input shardings and recompiles (None
+        # leaves the metrics to the compiler)
+        pin_inner, pin_outer = {}, {}
+        if self._shardings is not None:
+            carried = self._shardings[:3 if self._guarded else 2]
+            pin_inner = {"out_shardings": carried + (None,)}
+            pin_outer = {"out_shardings": self._shardings[:2]}
+        self._inner = jax.jit(inner, donate_argnums=donate, **pin_inner)
         outer = self.method.make_outer_step(self.cfg, tcfg)
         self._outer = (jax.jit(outer, donate_argnums=(0, 1) if not on_cpu
-                               else ())
+                               else (), **pin_outer)
                        if outer is not None else None)
+
+    def shard(self, mesh) -> None:
+        """Spread the run over ``mesh``: place params and optimizer state
+        with the method's pspecs (the health state replicated), split each
+        batch's rows over the data axes, and pin both jitted steps'
+        outputs to that placement, so every step — the outer resample,
+        whose fresh V draw has no input to inherit a sharding from,
+        included — keeps the G-axis split and no step recompiles.
+        Activation constraints bind to the ambient mesh
+        (``sharding.ctx.set_mesh``), which the caller sets first."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from ..sharding import rules
+
+        model = encdec if self.cfg.is_encoder_decoder else lm
+        p_ps, o_ps = self.method.pspecs(mesh, model.param_specs(self.cfg),
+                                        self.params, self.opt_state)
+        self._shardings = (rules.named_shardings(mesh, p_ps),
+                           rules.named_shardings(mesh, o_ps),
+                           NamedSharding(mesh, P()))   # health: replicated
+        self.params, self.opt_state, self.health = jax.device_put(
+            (self.params, self.opt_state, self.health), self._shardings)
+        base = self.loader
+
+        def loader(step):
+            batch = base(step)
+            rows = rules.batch_pspec(mesh, len(next(iter(batch.values()))))
+            return jax.device_put(batch, NamedSharding(mesh, P(rows)))
+
+        self.loader = loader
+        self._build_steps()
 
     @property
     def model_params(self):

@@ -65,6 +65,40 @@ def test_backward_matches_ref(m, k, n, r, route, monkeypatch):
                                rtol=2e-4, atol=5e-3)
 
 
+@pytest.mark.parametrize("m,k,n,r", [(33, 130, 650, 5), (64, 128, 384, 16)])
+def test_backward_n_chunked_matches_ref(m, k, n, r, monkeypatch):
+    # a budget that holds only part of dB forces the N-chunked kernel
+    # (partial dx summed in fp32 outside the kernel)
+    monkeypatch.setenv("REPRO_KERNEL_DISPATCH", "pallas")
+    sizes = (4,) * 5
+    monkeypatch.setattr(dispatch, "VMEM_BUDGET",
+                        dispatch._bwd_vmem_bytes(m, k, n, r, sizes, 2))
+    assert dispatch._bwd_chunks(m, k, n, r, sizes) == 2
+    dispatch.clear_kernel_cache()
+    _, w, v, b = _ops(m, k, n, r)
+    dy, p = _arr((m, n)), _arr((m, r))
+    dx, db = dispatch.lowrank_backward(dy, w, v, b, p)
+    (key,) = dispatch.kernel_cache_info()["keys"]
+    assert key[0] == "lowrank_backward" and key[4] == 2
+    np.testing.assert_allclose(
+        np.asarray(dx), np.asarray(dy @ w.T + (dy @ b) @ v.T),
+        rtol=2e-4, atol=5e-3)
+    np.testing.assert_allclose(np.asarray(db),
+                               np.asarray(dy).T @ np.asarray(p),
+                               rtol=2e-4, atol=5e-3)
+
+
+def test_route_log_records_each_choice(monkeypatch):
+    monkeypatch.setenv("REPRO_KERNEL_DISPATCH", "xla")
+    dispatch.clear_kernel_cache()
+    x, w, v, b = _ops(8, 16, 24, 2)
+    dispatch.lowrank_forward(x, w, v, b)
+    assert dispatch.route_log() == {
+        ("lowrank_forward", (8, 16, 24, 2)): "xla"}
+    dispatch.clear_kernel_cache()
+    assert dispatch.route_log() == {}
+
+
 @pytest.mark.parametrize("m,k,n,r", [(40, 50, 60, 6), (128, 256, 128, 16)])
 @pytest.mark.parametrize("route", ["pallas", "xla"])
 def test_merge_project_adam_ragged(m, k, n, r, route, monkeypatch):
@@ -281,6 +315,23 @@ def test_route_env_override(monkeypatch):
     monkeypatch.setenv("REPRO_KERNEL_DISPATCH", "palas")  # typo: fail loudly
     with pytest.raises(ValueError, match="REPRO_KERNEL_DISPATCH"):
         dispatch.route("lowrank_forward", shapes=(8, 8, 8, 2))
+
+
+def test_route_on_multi_device_mesh_is_xla(monkeypatch):
+    # Mosaic kernels cannot be partitioned by the compiler: under a mesh
+    # of more than one device every op takes the XLA schedule
+    from repro.sharding import ctx as shard_ctx
+
+    class _Mesh:
+        size, shape = 4, {"data": 2, "model": 2}
+
+    monkeypatch.delenv("REPRO_KERNEL_DISPATCH", raising=False)
+    monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
+    shapes = (128, 128, 128, 8)
+    assert dispatch.route("lowrank_forward", shapes=shapes) == "pallas"
+    monkeypatch.setattr(shard_ctx, "_MESH", _Mesh())
+    assert dispatch.route("lowrank_forward", shapes=shapes) == "xla"
+    assert dispatch.route("subspace_adam") == "xla"
 
 
 def test_route_auto_cpu_prefers_xla(monkeypatch):

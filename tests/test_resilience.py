@@ -123,6 +123,22 @@ def test_injected_nan_is_skipped_bit_identically(name):
         assert int(h3.consec_skips) == 0 and int(h3.total_skips) == 1
 
 
+@pytest.mark.parametrize("name", METHODS)
+def test_donated_state_holds_no_buffer_twice(name):
+    """On an accelerator the Trainer donates (params, opt_state, health)
+    to its jitted steps, and the runtime refuses a donating call that
+    gets one buffer as two arguments.  CPU runs never donate, so only a
+    chip would show it: check that every leaf owns its buffer, also after
+    the rollback re-arm."""
+    tr = Trainer(CFG, _tcfg(optimizer=name), _loader())
+    for h in (tr.health, health.after_rollback(tr.health)):
+        leaves = [x for x in jax.tree.leaves((tr.params, tr.opt_state, h))
+                  if x.size and not jax.dtypes.issubdtype(
+                      x.dtype, jax.dtypes.prng_key)]
+        ptrs = [x.unsafe_buffer_pointer() for x in leaves]
+        assert len(set(ptrs)) == len(ptrs)
+
+
 def test_injected_inf_is_skipped_too():
     tcfg = _tcfg()
     m = methods.get("lowrank_adam")

@@ -149,3 +149,66 @@ def test_sharded_checkpoint_roundtrip_subprocess():
     """G-sharded grouped params + state save -> restore bit-identically,
     with restore(shardings=...) landing leaves back on the mesh."""
     _run_sub(_SHARDED_CKPT)
+
+
+_TRAINER_SHARD = r"""
+import os
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                           " --xla_force_host_platform_device_count=4")
+import jax, numpy as np
+from repro import configs
+from repro.data.synthetic import StatelessLoader
+from repro.sharding import ctx
+from repro.train.trainer import Trainer
+
+cfg = configs.get_config("llama-tiny")
+tcfg = configs.TrainConfig(rank=8, lazy_k=2, warmup_steps=1, total_steps=3)
+
+def run(mesh=None):
+    loader = StatelessLoader("lm", seed=0, batch=4, seq_len=16,
+                             vocab=cfg.vocab_size)
+    tr = Trainer(cfg, tcfg, loader)
+    split = []
+    if mesh is not None:
+        tr.shard(mesh)
+        flat = jax.tree_util.tree_flatten_with_path(tr.opt_state)[0]
+        split = [(p, a.sharding.spec) for p, a in flat
+                 if "groups" in jax.tree_util.keystr(p)
+                 and a.sharding.spec and a.sharding.spec[0] is not None]
+        assert split, "no grouped buffer is G-split"
+    rep = tr.run(1)            # compiles the inner step
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda ev, dt, **kw: compiles.append(ev)
+        if ev == "/jax/core/compile/backend_compile_duration" else None)
+    rep.losses += tr.run(2).losses     # inner, outer + inner
+    return tr, rep, split, len(compiles)
+
+_, one, _, _ = run()
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+ctx.set_mesh(mesh)
+tr, four, split, compiles = run(mesh)
+ctx.set_mesh(None)
+np.testing.assert_allclose(four.losses, one.losses, rtol=1e-4)
+# the carried state keeps its placement: after the first step only the
+# outer step compiles, no inner step sees new input shardings
+assert compiles == 1, compiles
+after = dict(jax.tree_util.tree_flatten_with_path(tr.opt_state)[0])
+for path, spec in split:
+    a = after[path]
+    # the outer resample's fresh V keeps its placement: G-sliced over
+    # the four devices, not replicated
+    assert a.sharding.spec == spec, (path, a.sharding.spec, spec)
+    assert len({s.device for s in a.addressable_shards}) == 4
+    assert all(s.data.shape[0] < a.shape[0] for s in a.addressable_shards)
+print("OK", len(split))
+"""
+
+
+def test_trainer_shard_keeps_g_split_subprocess():
+    """``Trainer.shard`` places the grouped state on a (data=2, model=2)
+    mesh of host devices; the losses match the unsharded run, no step
+    after the first recompiles, and every G-split buffer, the resampled V
+    included, keeps its split after the outer step."""
+    _run_sub(_TRAINER_SHARD)

@@ -71,7 +71,7 @@ def test_hlo_cost_matches_xla_loop_free():
     a = jax.ShapeDtypeStruct((64, 64), jnp.float32)
     c = jax.jit(f).lower(a, a).compile()
     mine = hlo_cost.analyze(c.as_text())
-    assert np.isclose(mine["flops"], hlo_cost.xla_cost(c)["flops"],
+    assert np.isclose(mine["flops"], c.cost_analysis()["flops"],
                       rtol=0.01)
 
 
@@ -84,7 +84,7 @@ def test_hlo_cost_multiplies_scan_trip_count():
     ws = jax.ShapeDtypeStruct((5, 64, 64), jnp.float32)
     c = jax.jit(f).lower(h, ws).compile()
     mine = hlo_cost.analyze(c.as_text())
-    assert np.isclose(mine["flops"], 5 * hlo_cost.xla_cost(c)["flops"],
+    assert np.isclose(mine["flops"], 5 * c.cost_analysis()["flops"],
                       rtol=0.01)
 
 
