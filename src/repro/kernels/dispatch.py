@@ -182,6 +182,12 @@ def route(op: str, *, shapes: Tuple[int, ...] = (),
     return rt
 
 
+def _scope(op: str, rt: str):
+    """Name the routed op's work in the compiled program's op metadata
+    (and so in a profiler trace): ``repro.<op>.<route>``."""
+    return jax.named_scope(f"repro.{op}.{rt}")
+
+
 def route_log() -> dict:
     """``{(op, shapes): route}`` for every routed call since the last
     :func:`clear_kernel_cache` — which ops took the kernel and which the
@@ -599,10 +605,10 @@ def lowrank_forward(x: Array, w: Array, v: Array, b: Array, *,
     K = x.shape[-1]
     N, r = w.shape[1], v.shape[1]
     x2 = x.reshape(-1, K)
-    impl = TABLE["lowrank_forward"][route(
-        "lowrank_forward", shapes=(x2.shape[0], K, N, r),
-        dtypes=(x.dtype, w.dtype, v.dtype, b.dtype))]
-    out = impl(x2, w, v, b, return_p)
+    rt = route("lowrank_forward", shapes=(x2.shape[0], K, N, r),
+               dtypes=(x.dtype, w.dtype, v.dtype, b.dtype))
+    with _scope("lowrank_forward", rt):
+        out = TABLE["lowrank_forward"][rt](x2, w, v, b, return_p)
     if not return_p:
         return out.reshape(lead + (N,))
     y, p = out
@@ -629,10 +635,10 @@ def lowrank_batch_forward(x: Array, w: Array, v: Array, b: Array) -> Array:
             f"== x.shape[0]; got b {b.shape} vs x {x.shape}")
     B, S, K = x.shape
     N, r = w.shape[-1], v.shape[-1]
-    impl = TABLE["lowrank_batch_forward"][route(
-        "lowrank_batch_forward", shapes=(S, K, N, r),
-        dtypes=(x.dtype, w.dtype, v.dtype, b.dtype))]
-    return impl(x, w, v, b)
+    rt = route("lowrank_batch_forward", shapes=(S, K, N, r),
+               dtypes=(x.dtype, w.dtype, v.dtype, b.dtype))
+    with _scope("lowrank_batch_forward", rt):
+        return TABLE["lowrank_batch_forward"][rt](x, w, v, b)
 
 
 def lowrank_backward(dy: Array, w: Array, v: Array, b: Array, p: Array):
@@ -646,10 +652,10 @@ def lowrank_backward(dy: Array, w: Array, v: Array, b: Array, p: Array):
     lead = dy.shape[:-1]
     dy2 = dy.reshape(-1, N)
     p2 = p.reshape(-1, r)
-    impl = TABLE["lowrank_backward"][route(
-        "lowrank_backward", shapes=(dy2.shape[0], K, N, r),
-        dtypes=(dy.dtype, w.dtype, v.dtype, b.dtype, p.dtype))]
-    dx, db = impl(dy2, w, v, b, p2)
+    rt = route("lowrank_backward", shapes=(dy2.shape[0], K, N, r),
+               dtypes=(dy.dtype, w.dtype, v.dtype, b.dtype, p.dtype))
+    with _scope("lowrank_backward", rt):
+        dx, db = TABLE["lowrank_backward"][rt](dy2, w, v, b, p2)
     return dx.reshape(lead + (K,)), db
 
 
@@ -660,22 +666,22 @@ def lowrank_merge(w: Array, v: Array, b: Array) -> Array:
     accumulates in fp32 either way, so the stored weight never sees a
     double rounding.
     """
-    impl = TABLE["lowrank_merge"][route(
-        "lowrank_merge", dtypes=(w.dtype, v.dtype, b.dtype))]
-    fn = impl
+    rt = route("lowrank_merge", dtypes=(w.dtype, v.dtype, b.dtype))
+    fn = TABLE["lowrank_merge"][rt]
     for _ in range(w.ndim - 2):
         fn = jax.vmap(fn)
-    return fn(w, v, b)
+    with _scope("lowrank_merge", rt):
+        return fn(w, v, b)
 
 
 def lowrank_project(g: Array, v: Array) -> Array:
     """G^T V (N, r) fp32 — the Thm.-1 lift used by project-style baselines."""
-    impl = TABLE["lowrank_project"][route(
-        "lowrank_project", dtypes=(g.dtype, v.dtype))]
-    fn = impl
+    rt = route("lowrank_project", dtypes=(g.dtype, v.dtype))
+    fn = TABLE["lowrank_project"][rt]
     for _ in range(g.ndim - 2):
         fn = jax.vmap(fn)
-    return fn(g, v)
+    with _scope("lowrank_project", rt):
+        return fn(g, v)
 
 
 def lowrank_merge_sr(w: Array, v: Array, b: Array, bits: Array) -> Array:
@@ -686,12 +692,12 @@ def lowrank_merge_sr(w: Array, v: Array, b: Array, bits: Array) -> Array:
     stored master weights are bf16 so the once-per-K merge does not
     accumulate round-to-nearest bias across outer cycles.
     """
-    impl = TABLE["lowrank_merge_sr"][route(
-        "lowrank_merge_sr", dtypes=(w.dtype, v.dtype, b.dtype))]
-    fn = impl
+    rt = route("lowrank_merge_sr", dtypes=(w.dtype, v.dtype, b.dtype))
+    fn = TABLE["lowrank_merge_sr"][rt]
     for _ in range(w.ndim - 2):
         fn = jax.vmap(fn)
-    return fn(w, v, b, bits)
+    with _scope("lowrank_merge_sr", rt):
+        return fn(w, v, b, bits)
 
 
 def subspace_adam(b: Array, g: Array, m: Array, v: Array, *, lr, step,
@@ -723,8 +729,9 @@ def subspace_adam(b: Array, g: Array, m: Array, v: Array, *, lr, step,
         if plan.rows != flat[0].shape[0] or plan.r != r:
             plan = rank_pack_plan(flat[0].shape[0], r)
         flat = [_rank_pack(a, plan) for a in flat]
-    nb, nm, nv = impl(*flat, lr=lr, step=step, beta1=beta1, beta2=beta2,
-                      eps=eps, wd=wd)
+    with _scope("subspace_adam", rt):
+        nb, nm, nv = impl(*flat, lr=lr, step=step, beta1=beta1, beta2=beta2,
+                          eps=eps, wd=wd)
     if plan is not None and not plan.is_noop:
         nb, nm, nv = (_rank_unpack(o, plan) for o in (nb, nm, nv))
     return nb.reshape(shape), nm.reshape(shape), nv.reshape(shape)
@@ -750,7 +757,8 @@ def subspace_lion(b: Array, g: Array, m: Array, *, lr,
         if plan.rows != flat[0].shape[0] or plan.r != r:
             plan = rank_pack_plan(flat[0].shape[0], r)
         flat = [_rank_pack(a, plan) for a in flat]
-    nb, nm = impl(*flat, lr=lr, beta1=beta1, beta2=beta2, wd=wd)
+    with _scope("subspace_lion", rt):
+        nb, nm = impl(*flat, lr=lr, beta1=beta1, beta2=beta2, wd=wd)
     if plan is not None and not plan.is_noop:
         nb, nm = (_rank_unpack(o, plan) for o in (nb, nm))
     return nb.reshape(shape), nm.reshape(shape)
@@ -794,12 +802,13 @@ def subspace_adam_q8(b: Array, g: Array, mq: Array, ms: Array,
                dtypes=(b.dtype, g.dtype, ("int8", qblock),
                        ("int8", qblock)))
     impl = TABLE["subspace_adam_q8"][rt]
-    nb, nmq, nms, nvq, nvs = impl(
-        _to_blocks(b, R, qblock), _to_blocks(g, R, qblock),
-        _to_blocks(mq, R, qblock), ms.reshape(R, 1),
-        _to_blocks(vq, R, qblock), vs.reshape(R, 1),
-        _to_blocks(bits, R, qblock) if bits is not None else None,
-        lr=lr, step=step, beta1=beta1, beta2=beta2, eps=eps, wd=wd)
+    with _scope("subspace_adam_q8", rt):
+        nb, nmq, nms, nvq, nvs = impl(
+            _to_blocks(b, R, qblock), _to_blocks(g, R, qblock),
+            _to_blocks(mq, R, qblock), ms.reshape(R, 1),
+            _to_blocks(vq, R, qblock), vs.reshape(R, 1),
+            _to_blocks(bits, R, qblock) if bits is not None else None,
+            lr=lr, step=step, beta1=beta1, beta2=beta2, eps=eps, wd=wd)
 
     def unflat(a):
         return a.reshape(-1)[:size].reshape(shape)
@@ -820,11 +829,12 @@ def subspace_lion_q8(b: Array, g: Array, mq: Array, ms: Array, *, lr,
     rt = route("subspace_lion_q8",
                dtypes=(b.dtype, g.dtype, ("int8", qblock)))
     impl = TABLE["subspace_lion_q8"][rt]
-    nb, nmq, nms = impl(
-        _to_blocks(b, R, qblock), _to_blocks(g, R, qblock),
-        _to_blocks(mq, R, qblock), ms.reshape(R, 1),
-        _to_blocks(bits, R, qblock) if bits is not None else None,
-        lr=lr, beta1=beta1, beta2=beta2, wd=wd)
+    with _scope("subspace_lion_q8", rt):
+        nb, nmq, nms = impl(
+            _to_blocks(b, R, qblock), _to_blocks(g, R, qblock),
+            _to_blocks(mq, R, qblock), ms.reshape(R, 1),
+            _to_blocks(bits, R, qblock) if bits is not None else None,
+            lr=lr, beta1=beta1, beta2=beta2, wd=wd)
 
     def unflat(a):
         return a.reshape(-1)[:size].reshape(shape)

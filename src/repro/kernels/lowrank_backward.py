@@ -105,6 +105,7 @@ def lowrank_backward(dy: Array, w: Array, v: Array, b: Array, p: Array, *,
         ],
         scratch_shapes=[pltpu.VMEM((bm, K), jnp.float32)],
         interpret=interpret,
+        name="lowrank_backward",
     )(dy, w, v, b, p)
     if n_chunks == 1:
         return dx[0], db

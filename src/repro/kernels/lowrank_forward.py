@@ -104,6 +104,7 @@ def lowrank_forward(x: Array, w: Array, v: Array, b: Array, *,
             out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
             scratch_shapes=scratch,
             interpret=interpret,
+        name="lowrank_forward",
         )(x, w, v, b)
     return pl.pallas_call(
         functools.partial(_kernel_p, n_k=n_k),
@@ -119,4 +120,5 @@ def lowrank_forward(x: Array, w: Array, v: Array, b: Array, *,
         ],
         scratch_shapes=scratch,
         interpret=interpret,
+        name="lowrank_forward",
     )(x, w, v, b)

@@ -53,6 +53,7 @@ def lowrank_merge(w: Array, v: Array, b: Array, *, bk: int = 256,
         out_specs=pl.BlockSpec((bk, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((K, N), w.dtype),
         interpret=interpret,
+        name="lowrank_merge",
     )(w, v, b)
 
 
@@ -89,6 +90,7 @@ def lowrank_merge_sr(w: Array, v: Array, b: Array, bits: Array, *,
         out_specs=pl.BlockSpec((bk, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((K, N), w.dtype),
         interpret=interpret,
+        name="lowrank_merge_sr",
     )(w, v, b, bits)
 
 
@@ -129,4 +131,5 @@ def lowrank_project(g: Array, v: Array, *, bn: int = 256, bk: int = 256,
         out_shape=jax.ShapeDtypeStruct((N, r), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bn, r), jnp.float32)],
         interpret=interpret,
+        name="lowrank_project",
     )(g, v)

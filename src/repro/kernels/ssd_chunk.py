@@ -92,4 +92,5 @@ def ssd_intra_chunk(x: Array, dt: Array, da: Array, b: Array, c: Array, *,
             jax.ShapeDtypeStruct((BC, H, N, P), jnp.float32),
         ],
         interpret=interpret,
+        name="ssd_intra_chunk",
     )(x, dt, da, b, c)

@@ -102,6 +102,7 @@ def subspace_adam(b: Array, g: Array, m: Array, v: Array, *, lr, step,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((N, r), jnp.float32)] * 3,
         interpret=interpret,
+        name="subspace_adam",
     )(scalars, b, g, m, v)
 
 
@@ -141,6 +142,7 @@ def subspace_lion(b: Array, g: Array, m: Array, *, lr,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((N, r), jnp.float32)] * 2,
         interpret=interpret,
+        name="subspace_lion",
     )(scalars, b, g, m)
 
 
@@ -221,6 +223,7 @@ def subspace_adam_q8(b: Array, g: Array, mq: Array, ms: Array,
                    jax.ShapeDtypeStruct((R, L), jnp.int8),
                    jax.ShapeDtypeStruct((R, 1), jnp.float32)],
         interpret=interpret,
+        name="subspace_adam_q8",
     )(scalars, *operands)
 
 
@@ -276,4 +279,5 @@ def subspace_lion_q8(b: Array, g: Array, mq: Array, ms: Array, *, lr,
                    jax.ShapeDtypeStruct((R, L), jnp.int8),
                    jax.ShapeDtypeStruct((R, 1), jnp.float32)],
         interpret=interpret,
+        name="subspace_lion_q8",
     )(scalars, *operands)

@@ -40,6 +40,7 @@ def _chunk(x: Array, axis: int, size: int) -> Array:
     return x.reshape(shape)
 
 
+@jax.named_scope("repro.attention")
 def blockwise_attention(
     q: Array, k: Array, v: Array, *,
     causal: bool = True,

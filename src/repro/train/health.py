@@ -135,6 +135,12 @@ def guard_inner_step(step_fn: Callable, tcfg) -> Callable:
 
     def guarded(params, opt_state, health: HealthState, batch):
         cand_p, cand_s, metrics = step_fn(params, opt_state, batch)
+        with jax.named_scope("repro.guard"):
+            return accept_or_skip(params, opt_state, health, cand_p, cand_s,
+                                  metrics)
+
+    def accept_or_skip(params, opt_state, health: HealthState, cand_p,
+                       cand_s, metrics):
         loss = jnp.asarray(metrics["loss"], jnp.float32)
         gn = jnp.asarray(metrics.get("grad_norm", 0.0), jnp.float32)
         idx = health.seen

@@ -19,6 +19,7 @@ from ..sharding.ctx import constrain
 Array = jax.Array
 
 
+@jax.named_scope("repro.loss")
 def chunked_ce(hidden: Array, unembed, labels: Array, *,
                true_vocab: int, chunk: int = 512,
                label_mask: Optional[Array] = None):

@@ -120,8 +120,9 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
             (gsum, lsum), _ = jax.lax.scan(acc, (zeros, 0.0), micro)
             grads = jax.tree.map(lambda g: g / a, gsum)
             loss = lsum / a
-        new_params, _, new_state, gn = subspace.inner_update(
-            grads, trainable, params, opt_state, lr=lr, tcfg=tcfg)
+        with jax.named_scope("repro.update"):
+            new_params, _, new_state, gn = subspace.inner_update(
+                grads, trainable, params, opt_state, lr=lr, tcfg=tcfg)
         return new_params, new_state, {"loss": loss, "grad_norm": gn,
                                        "lr": lr}
 

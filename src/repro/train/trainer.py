@@ -250,9 +250,10 @@ class Trainer:
                  "health": self._health_extra()}
         if preempted:
             extra["preempted"] = True
-        ckpt.save(self.workdir, self.step,
-                  {"params": self.params, "opt": self.opt_state},
-                  keep=self.keep, extra=extra)
+        with jax.profiler.TraceAnnotation("repro.train.save"):
+            ckpt.save(self.workdir, self.step,
+                      {"params": self.params, "opt": self.opt_state},
+                      keep=self.keep, extra=extra)
 
     def _rollback(self, report: TrainerReport):
         """Escalation after ``max_consecutive_skips`` consecutive skips:
@@ -298,61 +299,72 @@ class Trainer:
         times: List[float] = []
         target = self.step + num_steps
         while self.step < target:
-            t0 = time.perf_counter()
-            if (self._outer is not None and self.step > 0 and
-                    self.step % self.tcfg.lazy_k == 0):
-                self.params, self.opt_state = jax.block_until_ready(
-                    self._outer(self.params, self.opt_state))
-            chaos.maybe_sigterm(self.step)   # fault injection (tests only)
-            batch = self.loader(self.step)
-            if self._guarded:
-                self.params, self.opt_state, self.health, metrics = \
-                    self._inner(self.params, self.opt_state, self.health,
-                                batch)
-                # ONE device->host fetch: the packed health vector carries
-                # loss + skip flag + consecutive-skip count + grad norm
-                hr = health.read_health(metrics)
-                loss = hr.loss
-                if not hr.ok:
-                    report.skipped_steps += 1
-                    report.last_anomaly_step = self.step
-                if hr.consec_skips >= self.tcfg.max_consecutive_skips:
-                    if self.rollbacks >= self.tcfg.max_rollbacks:
-                        # resilience budget exhausted: stop cleanly with
-                        # the last good state (skip semantics kept it
-                        # intact) instead of spinning forever
-                        report.health_exhausted = True
-                        self.save()
-                        break
-                    self._rollback(report)
-                    continue   # re-run from the restored step
-            else:
-                self.params, self.opt_state, metrics = self._inner(
-                    self.params, self.opt_state, batch)
-                loss = float(metrics["loss"])
-            dt = time.perf_counter() - t0
-            times.append(dt)
-            report.losses.append(loss)
-            report.step_times.append(dt)
-            # straggler watchdog
-            if len(times) >= 8:
-                med = float(np.median(times[-64:]))
-                if dt > self.straggler_factor * med:
-                    report.straggler_events += 1
-                    if self.on_straggler:
-                        self.on_straggler(self.step, dt, med)
-            self.step += 1
-            report.steps_run += 1
-            if log_every and self.step % log_every == 0:
-                print(f"step {self.step:6d} loss {loss:.4f} "
-                      f"({dt*1e3:.0f} ms)")
-            if self.checkpoint_every and \
-                    self.step % self.checkpoint_every == 0:
-                self.save()
-            if self._preempt:
-                # preemption drain: the in-flight step above COMPLETED
-                # before we got here — save it, tag the manifest, exit
-                self.save(preempted=True)
-                report.preempted = True
-                break
+            with jax.profiler.StepTraceAnnotation("repro.train.step",
+                                                  step_num=self.step):
+                t0 = time.perf_counter()
+                if (self._outer is not None and self.step > 0 and
+                        self.step % self.tcfg.lazy_k == 0):
+                    with jax.profiler.TraceAnnotation("repro.train.outer"):
+                        self.params, self.opt_state = jax.block_until_ready(
+                            self._outer(self.params, self.opt_state))
+                chaos.maybe_sigterm(self.step)   # fault injection (tests)
+                with jax.profiler.TraceAnnotation("repro.train.batch"):
+                    batch = self.loader(self.step)
+                if self._guarded:
+                    with jax.profiler.TraceAnnotation("repro.train.dispatch"):
+                        self.params, self.opt_state, self.health, metrics = \
+                            self._inner(self.params, self.opt_state,
+                                        self.health, batch)
+                    # ONE device->host fetch: the packed health vector
+                    # carries loss + skip flag + consecutive-skip count +
+                    # grad norm
+                    with jax.profiler.TraceAnnotation("repro.train.sync"):
+                        hr = health.read_health(metrics)
+                    loss = hr.loss
+                    if not hr.ok:
+                        report.skipped_steps += 1
+                        report.last_anomaly_step = self.step
+                    if hr.consec_skips >= self.tcfg.max_consecutive_skips:
+                        if self.rollbacks >= self.tcfg.max_rollbacks:
+                            # resilience budget exhausted: stop cleanly with
+                            # the last good state (skip semantics kept it
+                            # intact) instead of spinning forever
+                            report.health_exhausted = True
+                            self.save()
+                            break
+                        with jax.profiler.TraceAnnotation(
+                                "repro.train.rollback"):
+                            self._rollback(report)
+                        continue   # re-run from the restored step
+                else:
+                    with jax.profiler.TraceAnnotation("repro.train.dispatch"):
+                        self.params, self.opt_state, metrics = self._inner(
+                            self.params, self.opt_state, batch)
+                    with jax.profiler.TraceAnnotation("repro.train.sync"):
+                        loss = float(metrics["loss"])
+                dt = time.perf_counter() - t0
+                times.append(dt)
+                report.losses.append(loss)
+                report.step_times.append(dt)
+                # straggler watchdog
+                if len(times) >= 8:
+                    med = float(np.median(times[-64:]))
+                    if dt > self.straggler_factor * med:
+                        report.straggler_events += 1
+                        if self.on_straggler:
+                            self.on_straggler(self.step, dt, med)
+                self.step += 1
+                report.steps_run += 1
+                if log_every and self.step % log_every == 0:
+                    print(f"step {self.step:6d} loss {loss:.4f} "
+                          f"({dt*1e3:.0f} ms)")
+                if self.checkpoint_every and \
+                        self.step % self.checkpoint_every == 0:
+                    self.save()
+                if self._preempt:
+                    # preemption drain: the in-flight step above COMPLETED
+                    # before we got here — save it, tag the manifest, exit
+                    self.save(preempted=True)
+                    report.preempted = True
+                    break
         return report

@@ -8,13 +8,17 @@ for one chip of a described ``v5e:2x2`` topology, at the padded shapes the
 dispatch layer hands it for llama-100m (12 x d640 / ff1712, vocab 32128,
 r=128, 64 x 256 tokens per step) and at the widest qwen2-7b shape the VMEM
 guard admits.  Each case asserts that the program holds a
-``tpu_custom_call`` — the compiled kernel, not an XLA fallback.
+``tpu_custom_call`` — the compiled kernel, not an XLA fallback — named
+after the kernel's public function, so that a profiler trace tells the
+kernels apart by name.
 
 This is the only file that describes the topology.  The description loads
 the TPU library, which one process at a time may hold, so it happens in a
 module-scoped fixture (never at import): under several test workers only
 the worker given this file loads it.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -23,8 +27,10 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import dispatch
 from repro.kernels.lowrank_backward import lowrank_backward
 from repro.kernels.lowrank_forward import lowrank_forward
-from repro.kernels.lowrank_update import lowrank_merge, lowrank_merge_sr
-from repro.kernels.subspace_adam import subspace_adam, subspace_adam_q8
+from repro.kernels.lowrank_update import (lowrank_merge, lowrank_merge_sr,
+                                          lowrank_project)
+from repro.kernels.subspace_adam import (subspace_adam, subspace_adam_q8,
+                                         subspace_lion, subspace_lion_q8)
 
 BF16, F32, I8, U32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.uint32
 TOKENS = 64 * 256       # llama-100m rows per step: batch 64 x seq 256
@@ -63,6 +69,21 @@ def _compile(fn, one_chip, *specs) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+# the instruction name of each compiled Pallas call: %<kernel>[.N] = ...
+_KERNEL = re.compile(r"%([\w\-]+?)(?:\.\d+)? = [^\n]*"
+                     r'custom_call_target="tpu_custom_call"')
+
+
+def _assert_kernel(hlo: str, name: str) -> None:
+    """The program holds the compiled kernel, and every Pallas call in it
+    carries ``name`` (``vmap_<name>_`` under vmap; none is left as
+    ``_lambda_``)."""
+    assert "tpu_custom_call" in hlo
+    found = _KERNEL.findall(hlo)
+    assert found and all(name in k for k in found), found
+    assert "_lambda_" not in hlo
+
+
 def _fwd_specs(m, k, n, r=RANK):
     return ((m, k), BF16), ((k, n), BF16), ((k, r), BF16), ((n, r), BF16)
 
@@ -88,7 +109,7 @@ def test_lowrank_forward_compiles(case, one_chip):
     hlo = _compile(lambda x, w, v, b: lowrank_forward(x, w, v, b,
                                                       return_p=True),
                    one_chip, *_fwd_specs(m, k, n))
-    assert "tpu_custom_call" in hlo
+    _assert_kernel(hlo, "lowrank_forward")
 
 
 BWD = {
@@ -115,7 +136,7 @@ def test_lowrank_backward_compiles(case, one_chip, monkeypatch):
         dy, w, v, b, p, n_chunks=chunks), one_chip,
         ((m, n), BF16), ((k, n), BF16), ((k, RANK), BF16),
         ((n, RANK), BF16), ((m, RANK), BF16))
-    assert "tpu_custom_call" in hlo
+    _assert_kernel(hlo, "lowrank_backward")
 
 
 def test_lowrank_backward_refuses_what_cannot_fit(monkeypatch):
@@ -136,7 +157,7 @@ def test_subspace_adam_compiles(rows, one_chip):
     hlo = _compile(lambda b, g, m, v: subspace_adam(
         b, g, m, v, lr=1e-3, step=3.0, wd=0.05), one_chip,
         (s, F32), (s, F32), (s, F32), (s, F32))
-    assert "tpu_custom_call" in hlo
+    _assert_kernel(hlo, "subspace_adam")
 
 
 @pytest.mark.parametrize("master", ["float32", "bfloat16"])
@@ -150,7 +171,7 @@ def test_subspace_adam_q8_compiles(master, one_chip):
 
     hlo = _compile(fn, one_chip, (s, jnp.dtype(master)), (s, F32), (s, I8),
                    (sc, F32), (s, I8), (sc, F32), (s, U32))
-    assert "tpu_custom_call" in hlo
+    _assert_kernel(hlo, "subspace_adam_q8")
 
 
 # merge shapes: (K, N) of the grouped weight as padded to 256-blocks
@@ -163,7 +184,7 @@ def test_lowrank_merge_compiles(case, one_chip):
     k, n = MERGE[case]
     hlo = _compile(lowrank_merge, one_chip, ((k, n), BF16),
                    ((k, RANK), BF16), ((n, RANK), F32))
-    assert "tpu_custom_call" in hlo
+    _assert_kernel(hlo, "lowrank_merge")
 
 
 @pytest.mark.parametrize("case", sorted(MERGE))
@@ -171,7 +192,7 @@ def test_lowrank_merge_sr_compiles(case, one_chip):
     k, n = MERGE[case]
     hlo = _compile(lowrank_merge_sr, one_chip, ((k, n), BF16),
                    ((k, RANK), BF16), ((n, RANK), BF16), ((k, n), U32))
-    assert "tpu_custom_call" in hlo
+    _assert_kernel(hlo, "lowrank_merge_sr")
 
 
 def test_lowrank_batch_forward_compiles(one_chip):
@@ -184,4 +205,25 @@ def test_lowrank_batch_forward_compiles(one_chip):
 
     hlo = _compile(fn, one_chip, ((rows, s, k), BF16), ((k, n), BF16),
                    ((k, RANK), BF16), ((rows, n, RANK), BF16))
-    assert "tpu_custom_call" in hlo
+    _assert_kernel(hlo, "lowrank_forward")
+
+
+# the kernels no case above reaches, at small lane-dense shapes (the SSD
+# kernel is not among them: Mosaic has no lowering for its cumsum)
+NAMED = {
+    "lowrank_project": (lowrank_project,
+                        (((768, 1792), BF16), ((768, RANK), BF16))),
+    "subspace_lion": (
+        lambda b, g, m: subspace_lion(b, g, m, lr=1e-3),
+        (((4096, RANK), F32),) * 3),
+    "subspace_lion_q8": (
+        lambda b, g, mq, ms: subspace_lion_q8(b, g, mq, ms, lr=1e-3),
+        (((4096, 128), F32), ((4096, 128), F32), ((4096, 128), I8),
+         ((4096, 1), F32))),
+}
+
+
+@pytest.mark.parametrize("name", sorted(NAMED))
+def test_every_kernel_carries_its_name(name, one_chip):
+    fn, specs = NAMED[name]
+    _assert_kernel(_compile(fn, one_chip, *specs), name)
