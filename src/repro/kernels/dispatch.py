@@ -36,6 +36,18 @@ Mixed-precision contract (mirrored by kernels/ref.py):
   * subspace_adam: b/m/v are fp32 masters/moments in AND out; only the
     gradient may arrive in a reduced dtype (cast up once, in VMEM).
 
+Tiling: the padded dims are the same for every op (M to a multiple of 16
+up to 128 and of 128 beyond, N and K to multiples of 128).  The fused
+forward's blocks follow from the shape and the dtypes alone
+(:func:`_fwd_blocks`): the largest bm (a multiple of 16), bn and bk
+(multiples of 128) that divide the padded dims, stay under
+``FWD_MAX_BLOCKS`` and whose double-buffered working set fits the VMEM
+budget — the fewest grid steps, and on a tie the wider bn/bk.  At the
+12B widths that is 512 x 1024 x 1024; small shapes take their whole padded
+dims.  The route guard sizes exactly those blocks.  The backward takes
+128-blocks over M and N (:func:`_blocks`), the merge and the projection
+256-blocks.
+
 Kernel cache: every Pallas launch is built once per
 ``(op, padded shape, dtypes, blocks, statics)`` key and memoised in
 ``_KERNEL_CACHE`` — ragged shapes that pad to the same tile grid share one
@@ -160,14 +172,64 @@ def _bwd_chunks(M: int, K: int, N: int, r: int, sizes) -> Optional[int]:
     return None
 
 
-def _fwd_vmem_bytes(M: int, K: int, N: int, r: int, sizes) -> int:
-    """Per-operand itemsizes: (x, w, v, b) — y/p accumulators are fp32.
+def _fwd_tile_bytes(bm: int, bn: int, bk: int, r: int, sizes) -> int:
+    """Working set of one forward grid step at blocks (bm, bn, bk).
+
+    Per-operand itemsizes: (x, w, v, b) — y/p accumulators are fp32.
     Pipelined blocks are double-buffered, the scratch is single."""
     sx, sw, sv, sb = sizes
-    bm, _, bn, _, bk, _ = _blocks(M, N, K)
     blocks = (bm * bk * sx + bk * bn * sw + bk * r * sv + bn * r * sb
               + bm * bn * sx)               # + y output tile (x.dtype)
     return 2 * blocks + 4 * (bm * bn + bm * r)   # + f32 acc/accp scratch
+
+
+# largest forward blocks (bm, bn, bk): on a v5e sweep at the 12B widths
+# (PERF.md) they ran at 85-90% of the bf16 peak, the fastest block that
+# fits VMEM_BUDGET or within 0.4% of it
+FWD_MAX_BLOCKS = (512, 1024, 1024)
+
+
+def _divisors(n: int, step: int, cap: int) -> list:
+    """Multiples of ``step`` that divide ``n`` (itself a multiple of
+    ``step``), up to ``cap``, largest first."""
+    return [b for b in range(min(n, cap), 0, -1)
+            if b % step == 0 and n % b == 0]
+
+
+def _fwd_blocks(M: int, K: int, N: int, r: int, sizes):
+    """(bm, Mp, bn, Np, bk, Kp) of the fused forward.
+
+    The padded dims are :func:`_blocks`' (sublane 16 for M, lane 128 for
+    N and K), the same as the other ops'.  Within them the blocks are the
+    largest ``bm`` (a multiple of 16), ``bn`` and ``bk`` (multiples of
+    128) that divide the padded dims, stay under ``FWD_MAX_BLOCKS`` and
+    keep :func:`_fwd_tile_bytes` inside ``VMEM_BUDGET``: fewest grid
+    steps first, and on a tie the wider ``bn``/``bk``.  A shape that fits
+    no candidate keeps the 128-blocks of :func:`_blocks`, and the route
+    guard sends it to XLA.
+    """
+    bm, Mp, bn, Np, bk, Kp = _blocks(M, N, K)
+    cm, cn, ck = FWD_MAX_BLOCKS
+    best = None
+    for tn in _divisors(Np, LANE, cn):
+        for tk in _divisors(Kp, LANE, ck):
+            for tm in _divisors(Mp, SUBLANE, cm):
+                if _fwd_tile_bytes(tm, tn, tk, r, sizes) > VMEM_BUDGET:
+                    continue
+                key = ((Mp // tm) * (Np // tn) * (Kp // tk), -min(tn, tk))
+                if best is None or key < best[0]:
+                    best = (key, (tm, tn, tk))
+                break       # the largest bm that fits, for this bn, bk
+    if best is not None:
+        bm, bn, bk = best[1]
+    return bm, Mp, bn, Np, bk, Kp
+
+
+def _fwd_vmem_bytes(M: int, K: int, N: int, r: int, sizes) -> int:
+    """Working set of the fused forward at the blocks that
+    :func:`_fwd_blocks` picks for the shape: the kernel that runs."""
+    bm, _, bn, _, bk, _ = _fwd_blocks(M, K, N, r, sizes)
+    return _fwd_tile_bytes(bm, bn, bk, r, sizes)
 
 
 _ROUTES: dict = {}
@@ -345,7 +407,8 @@ def _pallas_forward(x2: Array, w: Array, v: Array, b: Array,
                     return_p: bool):
     M, K = x2.shape
     N, r = w.shape[1], v.shape[1]
-    bm, Mp, bn, Np, bk, Kp = _blocks(M, N, K)
+    bm, Mp, bn, Np, bk, Kp = _fwd_blocks(
+        M, K, N, r, tuple(_itemsize(a.dtype) for a in (x2, w, v, b)))
     itp = _interpret()
     fn = _cached_kernel(
         "lowrank_forward",

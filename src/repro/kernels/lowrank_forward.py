@@ -8,7 +8,13 @@ contraction (V is r columns, resident per K-tile), and the B^T term is a
 
 Tiling: grid (M/bm, N/bn, K/bk); x tile (bm, bk), w tile (bk, bn), v tile
 (bk, r); f32 scratch accumulators acc (bm, bn) and accp (bm, r) in VMEM.
-bm = bn = bk = 128 are MXU-native; r <= 512 keeps accp under 0.25 MB.
+The blocks need not be square: bm a multiple of 16, bn and bk multiples of
+128 (or the whole dimension).  ``kernels/dispatch.py`` picks them from the
+shapes and dtypes — the largest that divide the padded dimensions and keep
+the double-buffered working set in its VMEM budget — so that each grid step
+does enough MXU work to hide its DMA and its fixed cost.  The v tile is only
+read in the j == 0 slab; its block index stops changing after that slab, so
+the pipeline fetches it once per row of blocks, not once per step.
 
 Mixed precision: refs may carry different dtypes (bf16 compute slices over
 fp32 masters) — every contraction promotes its operands to a common dtype
@@ -26,6 +32,15 @@ from jax.experimental.pallas import tpu as pltpu
 from ._mixed import dotf as _dotf
 
 Array = jax.Array
+
+
+def _dot_nt(a: Array, b: Array) -> Array:
+    """a (m, r) @ b (n, r)^T, contracting r on both operands in place (no
+    transposed copy of b), fp32 accumulation as :func:`_dotf`."""
+    dt = jnp.promote_types(a.dtype, b.dtype)
+    return jax.lax.dot_general(a.astype(dt), b.astype(dt),
+                               (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 def _kernel(x_ref, w_ref, v_ref, b_ref, o_ref, acc_ref, accp_ref, *,
@@ -52,8 +67,8 @@ def _kernel(x_ref, w_ref, v_ref, b_ref, o_ref, acc_ref, accp_ref, *,
 
     @pl.when(k == n_k - 1)
     def _finish():
-        o_ref[...] = (acc_ref[...] + _dotf(
-            accp_ref[...], b_ref[...].T)).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] + _dot_nt(
+            accp_ref[...], b_ref[...])).astype(o_ref.dtype)
 
 
 def _kernel_p(x_ref, w_ref, v_ref, b_ref, o_ref, p_ref, acc_ref, accp_ref, *,
@@ -88,13 +103,19 @@ def lowrank_forward(x: Array, w: Array, v: Array, b: Array, *,
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
         pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-        pl.BlockSpec((bk, r), lambda i, j, k: (k, 0)),
+        # past the j == 0 slab v is not read: hold its block index at the
+        # slab's last one so that the pipeline issues no further copy
+        pl.BlockSpec((bk, r),
+                     lambda i, j, k: (jnp.where(j == 0, k, n_k - 1), 0)),
         pl.BlockSpec((bn, r), lambda i, j, k: (j, 0)),
     ]
     scratch = [
         pltpu.VMEM((bm, bn), jnp.float32),
         pltpu.VMEM((bm, r), jnp.float32),
     ]
+    # rows of blocks are independent; j carries accp and k carries acc
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary", "arbitrary"))
     if not return_p:
         return pl.pallas_call(
             functools.partial(_kernel, n_k=n_k),
@@ -103,8 +124,9 @@ def lowrank_forward(x: Array, w: Array, v: Array, b: Array, *,
             out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
             out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
             scratch_shapes=scratch,
+            compiler_params=params,
             interpret=interpret,
-        name="lowrank_forward",
+            name="lowrank_forward",
         )(x, w, v, b)
     return pl.pallas_call(
         functools.partial(_kernel_p, n_k=n_k),
@@ -119,6 +141,7 @@ def lowrank_forward(x: Array, w: Array, v: Array, b: Array, *,
             jax.ShapeDtypeStruct((M, r), x.dtype),
         ],
         scratch_shapes=scratch,
+        compiler_params=params,
         interpret=interpret,
         name="lowrank_forward",
     )(x, w, v, b)

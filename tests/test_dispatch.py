@@ -15,6 +15,7 @@ import pytest
 
 from repro.configs import TrainConfig
 from repro.kernels import dispatch, ref
+from repro.kernels.lowrank_forward import lowrank_forward as pl_forward
 from repro.models.linear import lowrank_matmul
 from repro.optim import subspace
 
@@ -48,6 +49,80 @@ def test_forward_matches_ref(m, k, n, r, route, monkeypatch):
                                rtol=2e-4, atol=2e-3)
     np.testing.assert_allclose(np.asarray(p), np.asarray(x @ v),
                                rtol=2e-4, atol=2e-3)
+
+
+# non-square blocks with two or more steps on every grid axis: the accp
+# carry across j, the v block held after the j == 0 slab, the p write-out
+@pytest.mark.parametrize("m,k,n,r,blocks", [
+    (96, 512, 768, 16, (48, 256, 128)),      # grid (2, 3, 4)
+    (32, 768, 256, 8, (16, 128, 384)),       # grid (2, 2, 2)
+])
+@pytest.mark.parametrize("return_p", [False, True])
+def test_forward_kernel_non_square_blocks_match_ref(m, k, n, r, blocks,
+                                                    return_p):
+    bm, bn, bk = blocks
+    x, w, v, b = _ops(m, k, n, r)
+    out = pl_forward(x, w, v, b, bm=bm, bn=bn, bk=bk, interpret=True,
+                     return_p=return_p)
+    y, p = out if return_p else (out, None)
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(ref.lowrank_forward(x, w, v, b)),
+                               rtol=2e-4, atol=2e-3)
+    if return_p:
+        np.testing.assert_allclose(np.asarray(p), np.asarray(x @ v),
+                                   rtol=2e-4, atol=2e-3)
+
+
+# (M, K, N, r) -> the forward's (bm, bn, bk): the NeMo-12B q, k/v, o,
+# gate/up, down and unembedding chunk; llama-100m and qwen2-7b as
+# tests/test_tpu_compile.py compiles them; a decode row block; a ragged one
+FWD_PICKS = {
+    (8192, 5120, 4096, 128): (512, 1024, 1024),
+    (8192, 5120, 1024, 128): (512, 1024, 1024),
+    (8192, 4096, 5120, 128): (512, 1024, 1024),
+    (8192, 5120, 14336, 128): (512, 1024, 1024),
+    (8192, 14336, 5120, 128): (512, 1024, 1024),
+    (1024, 5120, 16384, 128): (512, 1024, 1024),
+    (16384, 640, 640, 128): (512, 640, 640),
+    (16384, 640, 1792, 128): (512, 896, 640),
+    (16384, 1792, 640, 128): (512, 640, 896),
+    (16384, 640, 32256, 128): (512, 896, 640),
+    (16, 640, 32256, 128): (16, 896, 640),
+    (4096, 3584, 18944, 128): (512, 512, 896),
+    (16, 5120, 14336, 128): (16, 1024, 1024),
+    (33, 130, 650, 5): (48, 768, 256),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(FWD_PICKS))
+def test_forward_blocks_fit_the_padded_shape(shape):
+    m, k, n, r = shape
+    sizes = (2.0,) * 4      # bf16 operands
+    bm, mp, bn, np_, bk, kp = dispatch._fwd_blocks(m, k, n, r, sizes)
+    assert (bm, bn, bk) == FWD_PICKS[shape]
+    # the same padding as _blocks gives every op, and blocks that tile it
+    _, mp0, _, np0, _, kp0 = dispatch._blocks(m, n, k)
+    assert (mp, np_, kp) == (mp0, np0, kp0)
+    assert mp % bm == 0 and np_ % bn == 0 and kp % bk == 0
+    assert bm % dispatch.SUBLANE == 0
+    assert bn % dispatch.LANE == 0 and bk % dispatch.LANE == 0
+    assert dispatch._fwd_vmem_bytes(m, k, n, r, sizes) \
+        <= dispatch.VMEM_BUDGET
+
+
+def test_forward_blocks_fall_back_when_nothing_fits(monkeypatch):
+    # no block fits the budget: the 128-blocks stay, and the guard that
+    # sizes them sends the shape to XLA
+    monkeypatch.delenv("REPRO_KERNEL_DISPATCH", raising=False)
+    monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
+    m, k, n, r = 8192, 5120, 14336, 128
+    assert dispatch.route("lowrank_forward", shapes=(m, k, n, r),
+                          dtypes=(jnp.bfloat16,) * 4) == "pallas"
+    monkeypatch.setattr(dispatch, "VMEM_BUDGET", 2 ** 16)
+    assert dispatch._fwd_blocks(m, k, n, r, (2.0,) * 4) == \
+        tuple(dispatch._blocks(m, n, k))
+    assert dispatch.route("lowrank_forward", shapes=(m, k, n, r),
+                          dtypes=(jnp.bfloat16,) * 4) == "xla"
 
 
 @pytest.mark.parametrize("m,k,n,r", RAGGED + ALIGNED)

@@ -6,11 +6,12 @@ working set larger than the kernel's scoped VMEM.  Here every kernel the
 training and serving paths reach is compiled (not run) by the TPU compiler
 for one chip of a described ``v5e:2x2`` topology, at the padded shapes the
 dispatch layer hands it for llama-100m (12 x d640 / ff1712, vocab 32128,
-r=128, 64 x 256 tokens per step) and at the widest qwen2-7b shape the VMEM
-guard admits.  Each case asserts that the program holds a
-``tpu_custom_call`` — the compiled kernel, not an XLA fallback — named
-after the kernel's public function, so that a profiler trace tells the
-kernels apart by name.
+r=128, 64 x 256 tokens per step), at the widest qwen2-7b shape the VMEM
+guard admits and, for the forward, at NeMo-12B's widths (d5120 / ff14336,
+2 x 4096 tokens per step), each at the blocks the dispatch layer picks.
+Each case asserts that the program holds a ``tpu_custom_call`` — the
+compiled kernel, not an XLA fallback — named after the kernel's public
+function, so that a profiler trace tells the kernels apart by name.
 
 This is the only file that describes the topology.  The description loads
 the TPU library, which one process at a time may hold, so it happens in a
@@ -88,8 +89,18 @@ def _fwd_specs(m, k, n, r=RANK):
     return ((m, k), BF16), ((k, n), BF16), ((k, r), BF16), ((n, r), BF16)
 
 
+def _fwd_tiles(m, k, n, r=RANK):
+    """The forward's (bm, bn, bk) as the dispatch layer picks them for
+    bf16 operands."""
+    bm, _, bn, _, bk, _ = dispatch._fwd_blocks(m, k, n, r, (2,) * 4)
+    return dict(bm=bm, bn=bn, bk=bk)
+
+
+NEMO_TOKENS = 2 * 4096  # NeMo-12B rows per step: batch 2 x seq 4096
+
 # (M, K, N): the llama-100m q/k/v/o, up/gate, down and unembedding widths
-# as the dispatch layer pads them, and qwen2-7b's widest up-projection
+# as the dispatch layer pads them, qwen2-7b's widest up-projection, and
+# NeMo-12B's q, k/v, o, gate/up, down and unembedding chunk
 FWD = {
     "llama100m_attn": (TOKENS, 640, 640),
     "llama100m_up": (TOKENS, 640, 1792),
@@ -98,17 +109,25 @@ FWD = {
     # serving prefill: one prompt's last position through the unembedding
     "llama100m_prefill_logits": (16, 640, 32256),
     "qwen2_7b_up": (4096, 3584, 18944),
+    "nemo12b_q": (NEMO_TOKENS, 5120, 4096),
+    "nemo12b_kv": (NEMO_TOKENS, 5120, 1024),
+    "nemo12b_o": (NEMO_TOKENS, 4096, 5120),
+    "nemo12b_gate_up": (NEMO_TOKENS, 5120, 14336),
+    "nemo12b_down": (NEMO_TOKENS, 14336, 5120),
+    "nemo12b_unembed_chunk": (1024, 5120, 16384),
 }
 
 
 @pytest.mark.parametrize("case", sorted(FWD))
 def test_lowrank_forward_compiles(case, one_chip):
+    # the compiler must accept the kernel at the blocks the dispatch
+    # layer picks, within the working set its guard counts
     m, k, n = FWD[case]
     assert dispatch._fwd_vmem_bytes(m, k, n, RANK, (2,) * 4) \
         <= dispatch.VMEM_BUDGET
-    hlo = _compile(lambda x, w, v, b: lowrank_forward(x, w, v, b,
-                                                      return_p=True),
-                   one_chip, *_fwd_specs(m, k, n))
+    tiles = _fwd_tiles(m, k, n)
+    hlo = _compile(lambda x, w, v, b: lowrank_forward(
+        x, w, v, b, return_p=True, **tiles), one_chip, *_fwd_specs(m, k, n))
     _assert_kernel(hlo, "lowrank_forward")
 
 
@@ -199,9 +218,11 @@ def test_lowrank_batch_forward_compiles(one_chip):
     # serving prefill with one adapter per row: 4 rows of 128 tokens
     # through the vmapped 2-D kernel against the llama-100m up-projection
     rows, s, k, n = 4, 128, 640, 1792
+    tiles = _fwd_tiles(s, k, n)
 
     def fn(x, w, v, b):
-        return jax.vmap(lambda x2, b2: lowrank_forward(x2, w, v, b2))(x, b)
+        return jax.vmap(lambda x2, b2: lowrank_forward(
+            x2, w, v, b2, **tiles))(x, b)
 
     hlo = _compile(fn, one_chip, ((rows, s, k), BF16), ((k, n), BF16),
                    ((k, RANK), BF16), ((rows, n, RANK), BF16))
