@@ -27,6 +27,13 @@ fit is split over N (``_bwd_chunks``) before it falls back.  ``TABLE``
 maps op -> {route -> impl} and is deliberately a plain dict so tests can
 monkeypatch impls to assert the hot path really flows through here.
 
+Attention: ``route("attention", shapes=(B, S, Hq, Hkv, D), ...)`` is
+called by ``models/attention.py::blockwise_attention``, which decides
+alone whether the kernel (``kernels/flash_attention.py``) computes the
+call and says so in ``fits``: a call it does not takes ``xla``, the
+override included — unlike the low-rank kernels, the attention kernel
+does not pad.
+
 Mixed-precision contract (mirrored by kernels/ref.py):
 
   * forward:  y and p carry x.dtype; the y/p accumulators are fp32.
@@ -236,10 +243,13 @@ _ROUTES: dict = {}
 
 
 def route(op: str, *, shapes: Tuple[int, ...] = (),
-          dtypes: Sequence = (), itemsize: int = 4) -> str:
+          dtypes: Sequence = (), itemsize: int = 4,
+          fits: bool = True) -> str:
     """Pick 'pallas' or 'xla' for ``op`` and record the choice in
-    :func:`route_log` (keyed by ``(op, shapes)``)."""
-    rt = _route(op, shapes, dtypes, itemsize)
+    :func:`route_log` (keyed by ``(op, shapes)``).  ``fits=False``: the
+    caller's arguments are outside what the kernel computes, whatever
+    the shapes — the call takes XLA, the override included."""
+    rt = _route(op, shapes, dtypes, itemsize, fits)
     _ROUTES[(op, tuple(shapes))] = rt
     return rt
 
@@ -258,21 +268,25 @@ def route_log() -> dict:
 
 
 def _route(op: str, shapes: Tuple[int, ...], dtypes: Sequence,
-           itemsize: int) -> str:
+           itemsize: int, fits: bool = True) -> str:
     """Pick 'pallas' or 'xla' for ``op`` given (M, K, N, r)-style shapes.
 
     ``dtypes``: the op's operand dtypes in call order — the VMEM guard
     sizes each operand with its real itemsize (a bf16 working set is half
     the fp32 one; without this, bf16 workloads were spuriously routed to
     the XLA fallback).  ``itemsize`` is the uniform fallback when the
-    caller has no dtypes at hand.
+    caller has no dtypes at hand.  A call the kernel cannot compute
+    (``fits`` False) takes XLA before the override is read: the low-rank
+    kernels pad any shape, the attention kernel does not.
     """
     env = os.environ.get("REPRO_KERNEL_DISPATCH", "auto")
-    if env in ("pallas", "xla"):
-        return env
-    if env not in ("auto", ""):
+    if env not in ("pallas", "xla", "auto", ""):
         raise ValueError(
             f"REPRO_KERNEL_DISPATCH={env!r}: expected pallas, xla or auto")
+    if not fits:
+        return "xla"
+    if env in ("pallas", "xla"):
+        return env
     if jax.default_backend() != "tpu":
         return "xla"        # interpret-mode Pallas is a debug tool, not a path
     from ..sharding import ctx   # deferred: sharding imports optim

@@ -1,11 +1,22 @@
 """Attention: blockwise (flash-style) GQA, decode-with-cache, and MLA.
 
-Pure-JAX online-softmax blockwise attention.  Memory is O(S * chunk) instead
-of O(S^2): queries are processed in chunks (``lax.map``), keys/values are
-streamed in chunks (``lax.scan``), and both levels are rematerialised
-(``jax.checkpoint``) so the backward pass never holds full score matrices.
+:func:`blockwise_attention` takes one of two routes, chosen by
+``kernels/dispatch.py`` (``route("attention", ...)``) from what it can
+observe of the call:
 
-GQA is computed in grouped form — KV heads are never materialised repeated.
+* ``pallas`` — causal self-attention from position 0 at a head size and a
+  sequence of multiples of 128 in bf16, on one TPU device, outside a
+  serving prefill (``prefill``; decode never comes here): the Pallas
+  flash kernel of ``kernels/flash_attention.py`` (block-skipped causal
+  mask, grouped K/V, fp32 softmax state in VMEM, its own backward).
+* ``xla`` — every other call: pure-JAX online-softmax blockwise attention.
+  Memory is O(S * chunk) instead of O(S^2): queries are processed in
+  chunks (``lax.map``), keys/values are streamed in chunks (``lax.scan``),
+  and both levels are rematerialised (``jax.checkpoint``) so the backward
+  pass never holds full score matrices.
+
+Both compute GQA in grouped form — KV heads are never materialised
+repeated — and the same mathematics; only the schedule differs.
 
 Layout conventions:
   q: (B, Sq, Hq, D)   k: (B, Skv, Hkv, D)   v: (B, Skv, Hkv, Dv)
@@ -16,6 +27,10 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+
+from ..kernels import dispatch
+from ..kernels.flash_attention import flash_attention, flash_blocks
+from ..kernels.ops import _interpret
 
 Array = jax.Array
 
@@ -50,6 +65,7 @@ def blockwise_attention(
     kv_chunk: int = 1024,
     softmax_scale: Optional[float] = None,
     cp_groups: int = 1,
+    prefill: bool = False,
 ) -> Array:
     """Online-softmax attention, O(S * chunk) memory.
 
@@ -60,7 +76,36 @@ def blockwise_attention(
     contiguous groups folded into the batch dim (each group carries its own
     position offset; KV stays whole).  Used when heads don't divide the TP
     axis: the group dim is shardable over ``model`` (see lm.attn_apply).
+    ``prefill``: the call fills a serving KV cache; it keeps the XLA route,
+    whatever its shape (the kernel route is training attention).
+
+    q may arrive in a wider dtype than k (the model's fp32 rotary
+    output): it is rounded to k's dtype once — on the kernel route after
+    the softmax scale is folded in — and the output carries k's dtype.
     """
+    B, Sq, Hq, D = q.shape
+    _, Skv, Hkv, _ = k.shape
+    scale = softmax_scale if softmax_scale is not None else D ** -0.5
+    fits = (causal and not prefill and isinstance(q_offset, int)
+            and q_offset == 0 and kv_valid_len is None and Sq == Skv
+            and v.shape[-1] == D and cp_groups == 1
+            and flash_blocks(Sq, D, k.dtype) is not None)
+    rt = dispatch.route("attention", shapes=(B, Sq, Hq, Hkv, D),
+                        dtypes=(q.dtype, k.dtype, v.dtype), fits=fits)
+    with dispatch._scope("attention", rt):
+        if rt == "pallas":
+            return flash_attention(q, k, v, softmax_scale=scale,
+                                   interpret=_interpret())
+        return _blockwise(q.astype(k.dtype), k, v, causal=causal,
+                          q_offset=q_offset, kv_valid_len=kv_valid_len,
+                          q_chunk=q_chunk, kv_chunk=kv_chunk, scale=scale,
+                          cp_groups=cp_groups)
+
+
+def _blockwise(q: Array, k: Array, v: Array, *, causal: bool, q_offset,
+               kv_valid_len: Optional[Array], q_chunk: int, kv_chunk: int,
+               scale: float, cp_groups: int) -> Array:
+    """The XLA route of :func:`blockwise_attention`."""
     if cp_groups > 1 and q.shape[1] % cp_groups == 0 and q.shape[1] > 1:
         B, Sq, Hq, D = q.shape
         g = cp_groups
@@ -69,17 +114,16 @@ def blockwise_attention(
                 None)
         offs = q_offset + (Sq // g) * jnp.arange(g, dtype=jnp.int32)
         out = jax.vmap(
-            lambda qq, off: blockwise_attention(
+            lambda qq, off: _blockwise(
                 qq, k, v, causal=causal, q_offset=off,
                 kv_valid_len=kv_valid_len, q_chunk=q_chunk,
-                kv_chunk=kv_chunk, softmax_scale=softmax_scale),
+                kv_chunk=kv_chunk, scale=scale, cp_groups=1),
             in_axes=(1, 0), out_axes=1)(qg, offs)
         return out.reshape(B, Sq, Hq, out.shape[-1])
     B, Sq, Hq, D = q.shape
     _, Skv, Hkv, _ = k.shape
     Dv = v.shape[-1]
     G = Hq // Hkv
-    scale = softmax_scale if softmax_scale is not None else D ** -0.5
 
     # Pad awkward lengths (vlm prefix, whisper 1500) up to a chunk multiple
     # instead of degrading to tiny divisor chunks; padded keys are masked,

@@ -265,9 +265,13 @@ def attn_apply(h, p, cfg, *, pos_offset=0, cache=None, cache_index=None,
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     positions = jnp.asarray(pos_offset, jnp.int32)[..., None] + \
         jnp.arange(S, dtype=jnp.int32)
+    q_attn = q      # the query blockwise attention takes
     if cfg.rope_theta:
         posb = jnp.broadcast_to(positions, (B, S))
-        q = apply_rope(q, posb, cfg.rope_theta)
+        # left in fp32: blockwise attention rounds it once, after the
+        # softmax scale where its kernel route folds the scale into q
+        q_attn = apply_rope(q.astype(jnp.float32), posb, cfg.rope_theta)
+        q = q_attn.astype(q.dtype)
         k = apply_rope(k, posb, cfg.rope_theta)
 
     new_kv = None
@@ -292,9 +296,9 @@ def attn_apply(h, p, cfg, *, pos_offset=0, cache=None, cache_index=None,
                 S % get_mesh().shape["model"] == 0:
             cp = get_mesh().shape["model"]
         out = blockwise_attention(
-            q, k, v, causal=causal, q_offset=pos_offset,
+            q_attn, k, v, causal=causal, q_offset=pos_offset,
             q_chunk=cfg.attn_chunk // 2, kv_chunk=cfg.attn_chunk,
-            cp_groups=cp)
+            cp_groups=cp, prefill=cache is not None)
         if cache is not None:  # prefill: persist k/v
             ck, cv = cache
             new_kv = cache_update(ck, cv, k, v,

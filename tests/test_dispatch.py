@@ -425,3 +425,89 @@ def test_bf16_pallas_route(monkeypatch):
         np.asarray(y, np.float32),
         np.asarray(ref.lowrank_forward(x, w, v, b), np.float32),
         rtol=5e-2, atol=5e-2)
+
+
+# ---------------------------------------------------------------------------
+# Attention: the flash kernel where the call allows it, XLA elsewhere
+# ---------------------------------------------------------------------------
+
+NEMO_ATTN = (2, 4096, 32, 8, 128)     # (B, S, Hq, Hkv, D) of a NeMo-12B step
+
+# case -> ((B, S, Hq, Hkv, D), Dv or None, blockwise_attention kwargs,
+#          on a mesh of 4 devices, route)
+ATTN_ROUTES = {
+    "nemo_train": (NEMO_ATTN, None, {}, False, "pallas"),
+    "not_causal": (NEMO_ATTN, None, {"causal": False}, False, "xla"),
+    "q_offset": (NEMO_ATTN, None, {"q_offset": 128}, False, "xla"),
+    "kv_valid_len": (NEMO_ATTN, None, {"kv_valid_len": 4000}, False,
+                     "xla"),
+    "head_64": ((2, 4096, 32, 8, 64), None, {}, False, "xla"),
+    "mla_192_v128": ((2, 4096, 16, 16, 192), 128, {}, False, "xla"),
+    "seq_1500": ((2, 1500, 32, 8, 128), None, {}, False, "xla"),
+    "mesh": (NEMO_ATTN, None, {}, True, "xla"),
+    "prefill": (NEMO_ATTN, None, {"prefill": True}, False, "xla"),
+}
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The backend reads as a TPU for the route; the flash kernels built
+    for it (compiled, not interpreted) are dropped after the test."""
+    from repro.kernels import flash_attention as fa
+
+    monkeypatch.delenv("REPRO_KERNEL_DISPATCH", raising=False)
+    monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
+    dispatch.clear_kernel_cache()
+    yield
+    fa._kernel.cache_clear()
+
+
+@pytest.mark.parametrize("case", sorted(ATTN_ROUTES))
+def test_attention_route(case, monkeypatch, on_tpu):
+    from repro.models.attention import blockwise_attention
+    from repro.sharding import ctx as shard_ctx
+
+    class _Mesh:
+        size, shape = 4, {"data": 2, "model": 2}
+
+    shapes, dv, kw, on_mesh, want = ATTN_ROUTES[case]
+    b, s, hq, hkv, d = shapes
+    if on_mesh:
+        monkeypatch.setattr(shard_ctx, "_MESH", _Mesh())
+    if "kv_valid_len" in kw:
+        kw = {**kw, "kv_valid_len": jnp.asarray(kw["kv_valid_len"])}
+    args = [jax.ShapeDtypeStruct(x, jnp.bfloat16) for x in
+            ((b, s, hq, d), (b, s, hkv, d), (b, s, hkv, dv or d))]
+    # the route is taken at trace time: trace, compile nothing
+    out = jax.eval_shape(lambda q, k, v: blockwise_attention(q, k, v, **kw),
+                         *args)
+    assert out.shape == (b, s, hq, dv or d)
+    assert dispatch.route_log() == {("attention", shapes): want}
+
+
+# (the model call, the attention route it takes) at a D-128 bf16 model:
+# training forwards take the kernel, a serving prefill of the same shape
+# (a 128-token prompt from position 0) keeps XLA
+MODEL_ATTN = {"forward": "pallas", "prefill": "xla"}
+
+
+@pytest.mark.parametrize("call", sorted(MODEL_ATTN))
+def test_model_attention_route(call, on_tpu):
+    from repro.configs import get_config
+    from repro.models import lm
+
+    cfg = get_config("mistral-nemo-12b").reduced().replace(
+        head_dim=128, dtype="bfloat16", param_dtype="bfloat16")
+    b, s = 2, 128
+    params = jax.eval_shape(lambda: lm.init_params(cfg, jax.random.key(0)))
+    tokens = jax.ShapeDtypeStruct((b, s), jnp.int32)
+    if call == "forward":
+        jax.eval_shape(lambda p, t: lm.forward_hidden(p, t, cfg)[0],
+                       params, tokens)
+    else:
+        state = jax.eval_shape(lambda: lm.alloc_decode_state(cfg, b, s))
+        jax.eval_shape(lambda p, t, st: lm.prefill(p, t, cfg, st),
+                       params, tokens, state)
+    shape = (b, s, cfg.num_heads, cfg.num_kv_heads, 128)
+    assert {k: v for k, v in dispatch.route_log().items()
+            if k[0] == "attention"} == {("attention", shape): MODEL_ATTN[call]}

@@ -26,6 +26,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import dispatch
+from repro.kernels.flash_attention import flash_attention, flash_blocks
 from repro.kernels.lowrank_backward import lowrank_backward
 from repro.kernels.lowrank_forward import lowrank_forward
 from repro.kernels.lowrank_update import (lowrank_merge, lowrank_merge_sr,
@@ -229,9 +230,65 @@ def test_lowrank_batch_forward_compiles(one_chip):
     _assert_kernel(hlo, "lowrank_forward")
 
 
+def _attention_step(q, k, v, do):
+    """Forward and backward of causal flash attention, as a step runs it."""
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, softmax_scale=q.shape[-1] ** -0.5)
+        return jnp.sum(out.astype(F32) * do)
+
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def _attention_specs(b, s, hq, hkv, d):
+    return (((b, s, hq, d), F32), ((b, s, hkv, d), BF16),
+            ((b, s, hkv, d), BF16), ((b, s, hq, d), BF16))
+
+
+# name = type op(...) of every instruction, and each custom call's operands
+_DEF = re.compile(r"%([\w.\-]+) = (\w+)\[([\d,]*)\]")
+_CALL = re.compile(r'custom-call\(([^)]*)\), custom_call_target='
+                   r'"tpu_custom_call"')
+
+
+def _custom_call_operand_ranks(hlo: str) -> list:
+    """The rank of each operand, for every Pallas call in the program."""
+    rank = {n: len(dims.split(",")) if dims else 0
+            for n, _, dims in _DEF.findall(hlo)}
+    return [[rank.get(o, -1) for o in re.findall(r"%([\w.\-]+)", args)]
+            for args in _CALL.findall(hlo)]
+
+
+# (B, S, Hq, Hkv, D) of a training step's attention, and whether the
+# backward is fused there: a NeMo-12B step, and qwen3-moe-30b-a3b at 8k,
+# long enough for the split backward
+ATTENTION = {"nemo12b": ((2, 4096, 32, 8, 128), True),
+             "qwen3_moe_8k": ((1, 8192, 32, 4, 128), False)}
+
+
+@pytest.mark.parametrize("case", sorted(ATTENTION))
+def test_flash_attention_compiles(case, one_chip):
+    # forward and backward at the blocks the kernel picks for the shape;
+    # the query arrives in fp32, as the model hands it over
+    (b, s, hq, hkv, d), fused = ATTENTION[case]
+    assert flash_blocks(s, d, BF16).fused == fused
+    hlo = _compile(_attention_step, one_chip,
+                   *_attention_specs(b, s, hq, hkv, d))
+    _assert_kernel(hlo, "flash_attention")
+    # none of its calls reads as a low-rank kernel to the benchmark's
+    # cost model (three, four or five 2-D operands): its FLOPs are not
+    # low-rank FLOPs
+    calls = _custom_call_operand_ranks(hlo)
+    assert len(calls) == (2 if fused else 3)  # forward, dK/dV [, dQ]
+    for ranks in calls:
+        assert -1 not in ranks
+        assert not (len(ranks) in (3, 4, 5)
+                    and all(r == 2 for r in ranks)), ranks
+
+
 # the kernels no case above reaches, at small lane-dense shapes (the SSD
 # kernel is not among them: Mosaic has no lowering for its cumsum)
 NAMED = {
+    "flash_attention": (_attention_step, _attention_specs(1, 512, 4, 1, 128)),
     "lowrank_project": (lowrank_project,
                         (((768, 1792), BF16), ((768, RANK), BF16))),
     "subspace_lion": (
