@@ -50,7 +50,7 @@ def train_lr(cfg, sampler, steps, seed=0):
                        warmup_steps=0, total_steps=steps,
                        min_dim_for_lowrank=64, weight_decay=0.0, seed=seed)
     params = encoder_cls.init_params(cfg, N_CLASSES, jax.random.key(seed))
-    state = subspace.init(params, tcfg, jax.random.key(seed + 1))
+    params, state = subspace.init(params, tcfg, jax.random.key(seed + 1))
     loss_fn = make_loss(cfg)
 
     @jax.jit
@@ -69,7 +69,7 @@ def train_lr(cfg, sampler, steps, seed=0):
         params, state, loss = step(params, state, b)
     # merge pending subspace increment before eval
     params, state = outer(params, state)
-    return params
+    return subspace.params_of(params)
 
 
 def train_ipa(cfg, steps, seed=0):

@@ -68,8 +68,8 @@ def variants() -> Dict[str, TrainConfig]:
     return out
 
 
-# Serving-memory column: one ragged batch profile shared with
-# walltime_table's serving roofline (half the slots short, half long)
+# Serving-memory column: one ragged batch profile (slots of max_len / 1,
+# 2, 4 and 8 tokens, round-robin)
 SERVE_ARCHS = ("qwen2-7b", "deepseek-v2-236b", "mamba2-780m", "zamba2-7b")
 SERVE_BATCH, SERVE_MAX_LEN, SERVE_PAGE = 8, 4096, 64
 

@@ -3,7 +3,6 @@
   toy_mse          -> Figures 2-5 (estimator MSE vs samplers/c/samples)
   memory_table     -> Table 2 (peak training memory, every registered
                       method + the vanilla_lr ablation)
-  walltime_table   -> Table 3 (per-step wall clock, same method grid)
   finetune_table   -> Table 1 (LR fine-tuning accuracy across samplers)
   pretrain_curves  -> Figures 7-9 (Stiefel vs Gaussian LowRank-IPA)
   roofline_table   -> EXPERIMENTS.md §Roofline (from dry-run records)
@@ -17,12 +16,11 @@ import time
 import traceback
 
 from . import (finetune_table, memory_table, pretrain_curves, roofline_table,
-               toy_mse, walltime_table)
+               toy_mse)
 
 ALL = {
     "toy_mse": toy_mse.main,
     "memory_table": memory_table.main,
-    "walltime_table": walltime_table.main,
     "finetune_table": finetune_table.main,
     "pretrain_curves": pretrain_curves.main,
     "roofline_table": roofline_table.main,

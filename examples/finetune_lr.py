@@ -29,8 +29,10 @@ def loss_fn(packed, batch):
                   batch["labels"])
 
 
-params = encoder_cls.init_params(cfg, N_CLASSES, jax.random.key(0))
-state = subspace.init(params, tcfg, jax.random.key(1))
+# grouped master weights + grouped subspace state: the one training form
+params, state = subspace.init(
+    encoder_cls.init_params(cfg, N_CLASSES, jax.random.key(0)), tcfg,
+    jax.random.key(1))
 
 
 @jax.jit
@@ -45,6 +47,7 @@ outer = jax.jit(lambda p, s: subspace.outer_merge_resample(p, s, tcfg))
 
 
 def accuracy(params):
+    params = subspace.params_of(params)     # the model-shaped tree
     accs = []
     for i in range(6):
         b = classification_batch(99, i, batch=32, seq_len=32,

@@ -95,7 +95,7 @@ print(f"Adam state, low-rank   : {acct['adam_state_lowrank']:>10,} floats "
       f"({acct['adam_state_full']/acct['adam_state_lowrank']:.1f}x smaller)")
 
 # --- the projector satisfies the Theorem-2 optimality condition ------------
-state = subspace.init(params, tcfg, jax.random.key(1))
+_, state = subspace.init(params, tcfg, jax.random.key(1))
 print(f"\ngrouped state: {len(state.groups)} groups over "
       f"{sum(len(s.leaf_idx) for s in state.layout.groups)} low-rank leaves")
 v = state.groups[0].proj

@@ -16,27 +16,12 @@ ratio that flags remat/dispatch waste.
 from __future__ import annotations
 
 import re
-from typing import Dict, Optional
+from typing import Dict
 
 # TPU v5e hardware constants (assignment-specified)
 PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
 HBM_BW = 819e9               # bytes/s per chip
 ICI_BW = 50e9                # bytes/s per link (assignment: ~50 GB/s/link)
-HBM_BYTES = 16 * 2**30       # HBM capacity per chip (v5e: 16 GiB)
-
-
-def state_fits(per_device_state_bytes: int,
-               headroom: float = 0.6) -> bool:
-    """Does the resident training state leave room for activations?
-
-    ``per_device_state_bytes`` is the summed analytic footprint from
-    ``sharding.rules.lowrank_shard_report`` (masters + every optimizer
-    buffer under its pspec).  ``headroom`` caps state at that fraction of
-    :data:`HBM_BYTES` — the rest is activations, temps and XLA slack.
-    Used by the dry-run tables to flag cells whose G-sharding is the
-    difference between fitting and not.
-    """
-    return per_device_state_bytes <= headroom * HBM_BYTES
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1,
@@ -170,230 +155,8 @@ def active_param_count(cfg) -> int:
     return total - _routed_inactive(cfg)
 
 
-# ---------------------------------------------------------------------------
-# Low-rank kernel arithmetic intensity (fused vs unfused HBM traffic)
-# ---------------------------------------------------------------------------
-
 def _cdiv(a: int, b: int) -> int:
     return -(-a // b)
-
-
-_ITEMSIZE_NAME = {8: "f64", 4: "f32", 2: "bf16", 1: "f8e4m3fn"}
-
-
-def _operand_terms(op: str, m: int, k: int, n: int, r: int):
-    """Per-operand HBM traffic (element counts) of one low-rank op.
-
-    Returns ``(flops, fused_terms, unfused_terms)`` with each term list a
-    ``[(operand, elements)]`` sequence.  ``fused`` models the Pallas
-    kernels' actual BlockSpecs with grid-revisit-aware accounting (a
-    128-tiled kernel re-fetches W once per output row-strip, x once per
-    column-strip — operands are NOT streamed just once); ``unfused``
-    models autodiff's default schedule as independent tiled matmuls with
-    HBM round-trips for every intermediate.
-    """
-    ni, nj = _cdiv(m, 128), _cdiv(n, 128)
-    t = 128
-    if op == "lowrank_forward":
-        flops = 2 * m * k * n + 2 * m * k * r + 2 * m * r * n
-        # kernel BlockSpecs: x per j-slab, w per i-strip, v per (i, j) slab
-        # (its DMA is driven by the index map even though the j > 0 compute
-        # is skipped), b per i-strip; y and p written once.
-        fused = [("x", m * k * nj), ("w", k * n * ni),
-                 ("v", k * r * ni * nj), ("b", n * r * ni),
-                 ("y", m * n), ("p", m * r)]
-        # unfused: three tiled matmuls (x W, x V, p B^T) + the y0+y1 add.
-        unfused = [("x", m * k * (_cdiv(n, t) + _cdiv(r, t))),
-                   ("w", k * n * _cdiv(m, t)), ("v", k * r * _cdiv(m, t)),
-                   ("b", n * r * _cdiv(m, t)),
-                   ("p", m * r * (1 + _cdiv(n, t))), ("y", 5 * m * n)]
-    elif op == "lowrank_backward":
-        flops = 2 * m * n * k + 2 * m * n * r + 2 * m * r * k + 2 * m * n * r
-        # fused grid (i, j), full-K strips: dy once; w column-strip per i;
-        # v resident; b per (i, j); p per i-strip; dx written once; dB
-        # resident in VMEM, written once in fp32.
-        fused = [("dy", m * n), ("w", k * n * ni), ("v", k * r),
-                 ("b", n * r * ni), ("p", m * r), ("dx", m * k),
-                 ("db", n * r)]
-        # unfused: dy W^T, q = dy B (round-trips), q V^T, dx partial add,
-        # dy^T p (dy streamed a third time), dB in fp32.
-        unfused = [("dy", m * n * (_cdiv(k, t) + 2 * _cdiv(r, t))),
-                   ("w", k * n * _cdiv(m, t)), ("v", k * r * _cdiv(m, t)),
-                   ("b", n * r * _cdiv(m, t)),
-                   ("q", m * r * (1 + _cdiv(k, t))),
-                   ("p", m * r * _cdiv(n, t)), ("dx", 5 * m * k),
-                   ("db", n * r)]
-    elif op == "lowrank_merge":
-        flops = 2 * k * n * r
-        nik = _cdiv(k, 256)
-        fused = [("w", 2 * k * n), ("v", k * r), ("b", n * r * nik)]
-        # unfused: delta = V B^T materialised in fp32, then w + delta.
-        unfused = [("v", k * r * _cdiv(n, 256)),
-                   ("b", n * r * _cdiv(k, 256)),
-                   ("delta", 2 * k * n), ("w", 2 * k * n)]
-    elif op == "subspace_adam":
-        flops = 10 * n * r
-        # one round-trip of 4-in/3-out, split by storage class so per-dtype
-        # accounting can price them separately: the B master (read+write)
-        # vs the m/v moments (each read+write); g read once.
-        fused = [("b", 2 * n * r), ("moments", 4 * n * r), ("g", n * r)]
-        # ~10 elementwise HBM passes with intermediates round-tripping
-        # (b re-read by the delta add; m/v round-trip their own updates
-        # plus the bias-corrected intermediates)
-        unfused = [("b", 4 * n * r), ("moments", 10 * n * r),
-                   ("g", 2 * n * r)]
-    elif op == "subspace_lion":
-        flops = 7 * n * r
-        # momentum-only: b and m round-trip, g read once
-        fused = [("b", 2 * n * r), ("moments", 2 * n * r), ("g", n * r)]
-        # unfused: u = sign(...) materialises, m round-trips its update
-        unfused = [("b", 4 * n * r), ("moments", 5 * n * r),
-                   ("g", 2 * n * r)]
-    else:
-        raise ValueError(op)
-    return flops, fused, unfused
-
-
-def _operand_dtypes(op: str, stream: str) -> dict:
-    """Default dtype per operand: streamed tensors ride the compute dtype;
-    dB, the merge's materialised delta and the Adam state are fp32 by the
-    kernel contract (masters/moments/accumulators never downcast).  The
-    Adam *gradient* is fp32 too: it IS dB — the backward writes it fp32
-    and autodiff casts the packed-B cotangent back up to the fp32 master,
-    so no bf16 g-stream ever exists in the hot path."""
-    f32_always = {"db", "delta", "g"}
-    names = {
-        "lowrank_forward": ("x", "w", "v", "b", "y", "p"),
-        "lowrank_backward": ("dy", "w", "v", "b", "p", "q", "dx", "db"),
-        "lowrank_merge": ("w", "v", "b", "delta"),
-        "subspace_adam": ("b", "moments", "g"),
-        "subspace_lion": ("b", "moments", "g"),
-    }[op]
-    dt = {o: ("f32" if o in f32_always else stream) for o in names}
-    if op in ("subspace_adam", "subspace_lion"):
-        # optimizer state defaults: fp32 masters/moments regardless of the
-        # streaming dtype (overridden by state_dtype/master_dtype knobs)
-        dt["b"] = dt["moments"] = "f32"
-    return dt
-
-
-def lowrank_kernel_entry(op: str, m: int, k: int, n: int, r: int,
-                         itemsize: int = 2,
-                         dtypes: Optional[Dict[str, str]] = None) -> dict:
-    """FLOPs / HBM bytes / arithmetic intensity for one low-rank op.
-
-    Bytes are computed from PER-OPERAND dtypes: ``dtypes`` overrides the
-    defaults (keys per op, see :func:`_operand_dtypes`; values are HLO
-    dtype names like ``"bf16"``/``"f32"``), and ``itemsize`` sets the
-    default streaming dtype when no override is given — so a bf16 entry
-    halves exactly the operands the mixed-precision hot path halves while
-    dB / the Adam state stay 4-byte.  ``bytes_by_dtype`` breaks the totals
-    down per dtype.  The interesting entry is ``lowrank_backward``:
-    unfused, dy (m x n) is streamed by three separate contractions
-    (dy W^T, dy B, dy^T p) and q = dy B round-trips; fused, dy tiles are
-    read once.  Intensity compared against the v5e machine balance
-    PEAK_FLOPS / HBM_BW ≈ 240 FLOP/byte decides memory- vs compute-bound.
-    """
-    stream = _ITEMSIZE_NAME.get(itemsize, "f32")
-    dt = _operand_dtypes(op, stream)
-    if dtypes:
-        dt.update(dtypes)
-    flops, fused_terms, unfused_terms = _operand_terms(op, m, k, n, r)
-
-    def _bytes(terms):
-        total, by_dt = 0.0, {}
-        for operand, elems in terms:
-            size = _DTYPE_BYTES.get(dt[operand], itemsize)
-            b = float(elems) * size
-            total += b
-            by_dt[dt[operand]] = by_dt.get(dt[operand], 0.0) + b
-        return total, by_dt
-
-    fused, fused_by = _bytes(fused_terms)
-    unfused, unfused_by = _bytes(unfused_terms)
-    return {
-        "op": op, "m": m, "k": k, "n": n, "r": r,
-        "flops": float(flops),
-        "bytes_fused": float(fused), "bytes_unfused": float(unfused),
-        "bytes_by_dtype": {"fused": fused_by, "unfused": unfused_by},
-        "dtypes": dt,
-        "ai_fused": flops / fused, "ai_unfused": flops / unfused,
-        "machine_balance": PEAK_FLOPS / HBM_BW,
-        "bound_fused": "compute" if flops / fused > PEAK_FLOPS / HBM_BW
-                       else "memory",
-    }
-
-
-# knob-name -> HLO dtype name for the optimizer-state roofline terms
-_STATE_DTYPE_NAME = {"float32": "f32", "f32": "f32",
-                     "int8": "s8", "s8": "s8"}
-_MASTER_DTYPE_NAME = {"float32": "f32", "f32": "f32",
-                      "bfloat16": "bf16", "bf16": "bf16"}
-
-
-def lowrank_inner_step_bytes(groups, tokens: int,
-                             compute_dtype: str = "bf16",
-                             state_dtype: str = "float32",
-                             master_dtype: str = "float32",
-                             state_block: int = 128,
-                             algo: str = "adam") -> dict:
-    """Roofline-derived HBM bytes of ONE grouped inner training step.
-
-    ``groups``: iterable of ``(k, n, r, members)`` — one entry per
-    low-rank group (``members`` = stacked leaves); ``tokens``: flattened
-    batch*seq token count feeding each matmul.  Sums the fused forward +
-    fused backward per member plus the group's batched subspace update
-    (``algo`` = ``"adam"`` or ``"lion"``), with streamed operands in
-    ``compute_dtype`` and dB fp32 (the kernel contract).  Host-independent
-    by construction — this is the quantity the bench's bytes gates compare.
-
-    ``state_dtype`` prices the moment traffic: ``"int8"`` counts 1 byte
-    per element plus one fp32 absmax scale per ``state_block`` elements
-    (the fused dequant/requant round-trip touches payload AND scales).
-    ``master_dtype`` prices the B master stream (``"bfloat16"`` halves
-    it).  The returned ``state_bytes`` isolates the optimizer-state
-    traffic (B + moments + scales) — the quantity the int8 regression
-    gate compares against its fp32-state baseline.
-    """
-    sdt = _STATE_DTYPE_NAME[state_dtype]
-    mdt = _MASTER_DTYPE_NAME[master_dtype]
-    sub_op = "subspace_lion" if algo == "lion" else "subspace_adam"
-    total, by_dt, state_bytes = 0.0, {}, 0.0
-
-    def _add(name, b):
-        by_dt[name] = by_dt.get(name, 0.0) + b
-
-    for (k, n, r, members) in groups:
-        for op in ("lowrank_forward", "lowrank_backward", sub_op):
-            if op == sub_op:
-                dt = _operand_dtypes(op, compute_dtype)
-                dt["b"], dt["moments"] = mdt, sdt
-                e = lowrank_kernel_entry(op, 0, 0, members * n, r, dtypes=dt)
-                mult = 1
-            else:
-                e = lowrank_kernel_entry(op, tokens, k, n, r,
-                                         dtypes=_operand_dtypes(
-                                             op, compute_dtype))
-                mult = members
-            total += mult * e["bytes_fused"]
-            for name, b in e["bytes_by_dtype"]["fused"].items():
-                _add(name, mult * b)
-            if op == sub_op:
-                fused = dict(_operand_terms(op, 0, 0, members * n, r)[1])
-                b_bytes = fused["b"] * _DTYPE_BYTES[mdt]
-                mo_bytes = fused["moments"] * _DTYPE_BYTES[sdt]
-                scale_bytes = 0.0
-                if sdt == "s8":
-                    # one fp32 scale rides each state_block-element block
-                    # of every moment read/write the kernel performs
-                    scale_bytes = fused["moments"] / state_block * 4.0
-                    total += scale_bytes
-                    _add("f32", scale_bytes)
-                state_bytes += b_bytes + mo_bytes + scale_bytes
-    return {"bytes": total, "by_dtype": by_dt, "state_bytes": state_bytes,
-            "compute_dtype": compute_dtype, "state_dtype": state_dtype,
-            "master_dtype": master_dtype, "state_block": int(state_block),
-            "algo": algo, "tokens": tokens}
 
 
 # ---------------------------------------------------------------------------
@@ -456,14 +219,13 @@ def serve_decode_bytes(groups, batch: int, tenants: int,
     """Weight-stream HBM bytes of ONE multi-tenant batched decode step,
     lazy vs merged.
 
-    ``groups``: iterable of ``(k, n, r, members)`` as in
-    :func:`lowrank_inner_step_bytes`.  Lazy serving streams each base W
+    ``groups``: iterable of ``(k, n, r, members)``, one entry per low-rank
+    group (``members`` = stacked leaves).  Lazy serving streams each base W
     once, the shared V once, and one rank-r B per decode row
     (``k n + k r + batch·n r`` elements per member leaf); merged serving
     of ``tenants`` distinct adapter sets must stream a full (k, n) weight
     per tenant (``tenants·k n``) — the traffic the paged engine's
-    ``W + V Bᵀ`` path avoids, and the quantity the bench's serve gate
-    floors."""
+    ``W + V Bᵀ`` path avoids."""
     sz = _DTYPE_BYTES.get(compute_dtype, 2)
     lazy = merged = 0.0
     for (k, n, r, members) in groups:

@@ -9,7 +9,7 @@ jnp expressions itself.  Per call the dispatcher picks a route:
     ragged operands (lane = 128, sublane = 8/16): inputs are zero-padded up
     to block multiples and outputs sliced back, so the old hard
     ``assert K % bk == 0`` never bites callers.  Off the TPU the kernels
-    run in interpret mode (kernels/ops.py ``_interpret``).
+    run in interpret mode (:func:`_interpret`).
   * ``xla`` — the pure-jnp reference path (kernels/ref.py-style expressions
     with fp32 accumulation), which XLA fuses well on CPU/GPU and which
     serves as the fallback when a Pallas kernel's VMEM working set
@@ -84,7 +84,6 @@ from .lowrank_forward import lowrank_forward as _pl_forward
 from .lowrank_update import lowrank_merge as _pl_merge
 from .lowrank_update import lowrank_merge_sr as _pl_merge_sr
 from .lowrank_update import lowrank_project as _pl_project
-from .ops import _interpret
 from .subspace_adam import subspace_adam as _pl_adam
 from .subspace_adam import subspace_adam_q8 as _pl_adam_q8
 from .subspace_adam import subspace_lion as _pl_lion
@@ -95,6 +94,14 @@ Array = jax.Array
 LANE = 128           # TPU lane count: minor-dim tiling granularity
 SUBLANE = 16         # sublane granularity (16 covers bf16; 8 would do f32)
 VMEM_BUDGET = 12 * 2 ** 20   # conservative slice of the ~16 MB/core VMEM
+
+
+def _interpret() -> bool:
+    """Off the TPU the Pallas kernels run in ``interpret=True`` mode (the
+    kernel body executes eagerly in Python).  Decided by the backend
+    alone, so a chip run always compiles to Mosaic and never times the
+    interpreter."""
+    return jax.default_backend() != "tpu"
 
 
 def _round_up(x: int, m: int) -> int:

@@ -90,7 +90,7 @@ class Method(abc.ABC):
         return params, opt_state
 
     def describe(self) -> Dict[str, str]:
-        """Human/table-facing description (memory & walltime tables).
+        """Human/table-facing description (the memory table).
 
         Subclasses override the defaults; every key here is part of the
         contract, so a minimally-registered method (just the three

@@ -25,7 +25,7 @@ class GaLoreMethod(Method):
     family = "bp"
 
     def init(self, params, tcfg, key):
-        return galore.init_grouped(params, tcfg, key)
+        return galore.init(params, tcfg, key)
 
     def make_inner_step(self, cfg, tcfg,
                         loss_fn: Optional[Callable] = None) -> Callable:

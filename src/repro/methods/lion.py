@@ -35,7 +35,7 @@ class LowRankLionMethod(_LowRankBase):
     family = "bp"
 
     def init(self, params, tcfg, key):
-        return subspace.init_grouped(params, tcfg, key, algo="lion")
+        return subspace.init(params, tcfg, key, algo="lion")
 
     def make_inner_step(self, cfg, tcfg,
                         loss_fn: Optional[Callable] = None) -> Callable:

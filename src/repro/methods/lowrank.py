@@ -2,7 +2,7 @@
 
 Both share the grouped structure-of-arrays machinery of
 :mod:`repro.optim.subspace` — grouped master weights + grouped subspace
-state built once by ``subspace.init_grouped``, batched kernels through the
+state built once by ``subspace.init``, batched kernels through the
 dispatch layer, and the lazy outer merge+resample — and differ only in how
 the subspace gradient ``g_B`` is produced: autodiff through the LRPack
 path (IPA) vs the antithetic two-point forward-only estimate (LR/ZO).
@@ -27,7 +27,7 @@ class _LowRankBase(Method):
         # structure-of-arrays layout as the state): both jitted steps
         # consume weight slices lazily and the outer merge is a pure
         # batched W += V B^T on the stacked buffer.
-        return subspace.init_grouped(params, tcfg, key)
+        return subspace.init(params, tcfg, key)
 
     def make_outer_step(self, cfg, tcfg) -> Optional[Callable]:
         if getattr(tcfg, "fuse_outer", False):

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 
 from ..kernels import dispatch
 from ..kernels.flash_attention import flash_attention, flash_blocks
-from ..kernels.ops import _interpret
 
 Array = jax.Array
 
@@ -95,7 +94,7 @@ def blockwise_attention(
     with dispatch._scope("attention", rt):
         if rt == "pallas":
             return flash_attention(q, k, v, softmax_scale=scale,
-                                   interpret=_interpret())
+                                   interpret=dispatch._interpret())
         return _blockwise(q.astype(k.dtype), k, v, causal=causal,
                           q_offset=q_offset, kv_valid_len=kv_valid_len,
                           q_chunk=q_chunk, kv_chunk=kv_chunk, scale=scale,

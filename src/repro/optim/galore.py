@@ -28,34 +28,30 @@ import jax.numpy as jnp
 from ..kernels import dispatch
 from ..models.common import compute_view as _compute_view
 from ..models.common import resolve_compute_dtype
+from . import subspace
 from .adamw import clip_by_global_norm
 from .subspace import GroupedLowRankSlot, SubspaceState, _dense_adam
 
 Array = jax.Array
 
 
-def init(params, tcfg, key: Array) -> SubspaceState:
-    """Same grouped slot layout as LowRankLazyAdam; V starts as zeros (the
-    first refresh fills it from the first gradient's SVD).
+def init(params, tcfg,
+         key: Array) -> Tuple[subspace.GroupedParams, SubspaceState]:
+    """``(GroupedParams, state)`` from the model tree ``params``: the same
+    grouped master weights and slot layout as LowRankLazyAdam, so GaLore's
+    per-step weight write happens on the stacked buffers with zero
+    stack/unstack.  U starts as zeros (the first refresh fills it from the
+    first gradient's SVD).
 
     GaLore opts OUT of quantized/narrow optimizer state
     (``quantize_state=False``): its moment math below runs in plain XLA on
     the logical fp32 views, not through the fused dequant-in-VMEM q8
     kernels, so ``state_dtype``/``master_dtype`` would buy nothing here and
     the slots stay fp32 regardless of the knobs."""
-    from . import subspace
-    state = subspace.init(params, tcfg, key, quantize_state=False)
+    state = subspace._init_state(params, tcfg, key, quantize_state=False)
     groups = tuple(g._replace(proj=jnp.zeros_like(g.proj))
                    for g in state.groups)
-    return dataclasses.replace(state, groups=groups)
-
-
-def init_grouped(params, tcfg, key: Array):
-    """(GroupedParams, state) — grouped master weights, like the trainer's
-    LowRankLazyAdam entry: GaLore's per-step weight write then happens on
-    the stacked buffers with zero stack/unstack."""
-    from . import subspace
-    state = init(params, tcfg, key)
+    state = dataclasses.replace(state, groups=groups)
     return subspace.group_params(params, state.layout), state
 
 
@@ -75,16 +71,12 @@ def _top_r_basis(g: Array, r: int) -> Array:
 def value_and_full_grads(loss_fn, params, batch):
     """GaLore's step 1: classical full backprop (the memory cost).
 
-    With grouped master weights the gradient arrives in the SAME grouped
-    layout (a ``GroupedParams`` cotangent whose ``groups[g]`` are already
-    stacked ``(G,)+lead+(k, n)`` buffers) — the per-group gradient stack
-    below disappears along with the weight stack.
+    The gradient arrives in the grouped layout of the master weights (a
+    ``GroupedParams`` cotangent whose ``groups[g]`` are already stacked
+    ``(G,)+lead+(k, n)`` buffers), so no per-group gradient stack exists.
     """
-    from . import subspace
-    if isinstance(params, subspace.GroupedParams):
-        return jax.value_and_grad(
-            lambda gp: loss_fn(subspace.params_of(gp), batch))(params)
-    return jax.value_and_grad(loss_fn)(params, batch)
+    return jax.value_and_grad(
+        lambda gp: loss_fn(subspace.params_of(gp), batch))(params)
 
 
 def update(full_grads, params, state: SubspaceState, *, lr, tcfg,
@@ -94,29 +86,18 @@ def update(full_grads, params, state: SubspaceState, *, lr, tcfg,
     GaLore updates W directly every step (no lazy B accumulation):
       R = U^T G ;  Adam(R) -> delta ;  W -= lr * U @ delta.
     Per group the projection R runs as ONE batched
-    ``dispatch.lowrank_project`` call over the stacked gradients; on
-    grouped master weights the per-step weight write is a pure batched
-    subtract on the stacked buffer (no stack/unstack at all).
+    ``dispatch.lowrank_project`` call over the stacked gradients, and the
+    per-step weight write is a pure batched subtract on the stacked buffer
+    (no stack/unstack at all).
     """
-    from . import subspace
-    grouped = isinstance(params, subspace.GroupedParams)
     full_grads, _ = clip_by_global_norm(full_grads, tcfg.grad_clip)
     step = state.step + 1
     b1, b2, eps = tcfg.beta1, tcfg.beta2, tcfg.eps
     bc1 = 1.0 - b1 ** step.astype(jnp.float32)
     bc2 = 1.0 - b2 ** step.astype(jnp.float32)
 
-    if grouped:
-        dense_w, dense_g = params.dense, full_grads.dense
-    else:
-        flat_p, pdef = jax.tree.flatten(params)
-        flat_g = pdef.flatten_up_to(full_grads)
-        new_flat_p = list(flat_p)
-        dense_w = tuple(flat_p[i] for i in state.layout.dense_idx)
-        dense_g = tuple(flat_g[i] for i in state.layout.dense_idx)
-
     new_dense_w, new_dense = [], []
-    for di, (w, g) in enumerate(zip(dense_w, dense_g)):
+    for di, (w, g) in enumerate(zip(params.dense, full_grads.dense)):
         new_p, slot = _dense_adam(state.dense[di], w, g,
                                   lr=lr, bc1=bc1, bc2=bc2, tcfg=tcfg)
         new_dense_w.append(new_p)
@@ -125,14 +106,8 @@ def update(full_grads, params, state: SubspaceState, *, lr, tcfg,
     new_wgroups, new_groups = [], []
     for g_i, (spec, slot) in enumerate(zip(state.layout.groups,
                                            state.groups)):
-        if grouped:
-            gs = full_grads.groups[g_i].astype(jnp.float32)
-            ws = params.groups[g_i].astype(jnp.float32)
-        else:
-            gs = jnp.stack([flat_g[i].astype(jnp.float32)
-                            for i in spec.leaf_idx])   # (G,)+lead+(k,n)
-            ws = jnp.stack([flat_p[i].astype(jnp.float32)
-                            for i in spec.leaf_idx])
+        gs = full_grads.groups[g_i].astype(jnp.float32)
+        ws = params.groups[g_i].astype(jnp.float32)
         r = spec.rank
         fn = _top_r_basis
         for _ in range(gs.ndim - 2):
@@ -156,22 +131,14 @@ def update(full_grads, params, state: SubspaceState, *, lr, tcfg,
         if tcfg.weight_decay:
             lifted = lifted + tcfg.weight_decay * ws
         new_ws = ws - lr * lifted
-        if grouped:
-            new_wgroups.append(new_ws.astype(params.groups[g_i].dtype))
-        else:
-            for j, i in enumerate(spec.leaf_idx):
-                new_flat_p[i] = new_ws[j].astype(flat_p[i].dtype)
+        new_wgroups.append(new_ws.astype(params.groups[g_i].dtype))
         new_groups.append(GroupedLowRankSlot(proj=proj, b=slot.b, m=m, v=v,
                                              energy=slot.energy))
     new_state = dataclasses.replace(state, dense=tuple(new_dense),
                                     groups=tuple(new_groups), step=step)
-    if grouped:
-        return subspace.GroupedParams(
-            dense=tuple(new_dense_w), groups=tuple(new_wgroups),
-            layout=params.layout, treedef=params.treedef), new_state
-    for di, i in enumerate(state.layout.dense_idx):
-        new_flat_p[i] = new_dense_w[di]
-    return jax.tree.unflatten(pdef, new_flat_p), new_state
+    return subspace.GroupedParams(
+        dense=tuple(new_dense_w), groups=tuple(new_wgroups),
+        layout=params.layout, treedef=params.treedef), new_state
 
 
 def make_train_step(cfg, tcfg, loss_fn=None):

@@ -30,15 +30,15 @@ Master-weight layout — grouped end-to-end:
   The master weights mirror the state: :class:`GroupedParams` keeps every
   group's member weights pre-stacked as one ``(G,) + lead + (k, n_out)``
   buffer (non-grouped leaves pass through untouched in ``dense``), built
-  once by :func:`group_params` / :func:`init_grouped` and carried through
-  the whole training loop.  ``outer_merge_resample`` on a GroupedParams is
-  then a pure batched ``W += V B^T`` on the already-stacked buffer — zero
-  per-leaf stack/unstack anywhere in the outer step — and the inner step /
-  loss eval consume weight *slices* exactly the way :func:`packed_params`
-  already slices B/V.  :func:`params_of` rebuilds the model-shaped tree at
-  the API boundary (checkpoint templates, serving, introspection); every
-  public entry point here accepts either representation, with the raw-tree
-  path kept as the per-leaf-weights reference.
+  once by :func:`init` and carried through the whole training loop.
+  ``outer_merge_resample`` is then a pure batched ``W += V B^T`` on the
+  already-stacked buffer — zero per-leaf stack/unstack anywhere in the
+  outer step — and the inner step / loss eval consume weight *slices*
+  exactly the way :func:`packed_params` already slices B/V.
+  :func:`params_of` rebuilds the model-shaped tree at the API boundary
+  (checkpoint templates, serving, introspection); the per-leaf ``_ref``
+  functions at the bottom of this module work on that tree and are the
+  test references.
 
 Mixed precision (the ``compute_dtype`` knob, default bf16 on TPU/GPU):
   The layout pins a compute dtype (``SubspaceLayout.compute_dtype``).  V
@@ -278,7 +278,7 @@ def build_layout(params, tcfg, algo: str = "adam",
 
 # ---------------------------------------------------------------------------
 # Sampling (grouped: one batched draw per group; per-leaf kept for the
-# ungrouped reference path and checkpoint migration)
+# reference path and checkpoint migration)
 # ---------------------------------------------------------------------------
 
 def _sample_v(name, key, k_dim, r, c, energy=None, dtype=jnp.float32):
@@ -348,8 +348,8 @@ def _moment_zeros(shape, layout: SubspaceLayout, codec: str = "linear"):
     return jnp.zeros(shape, jnp.float32)
 
 
-def init(params, tcfg, key: Array, algo: str = "adam",
-         quantize_state: bool = True) -> SubspaceState:
+def _init_state(params, tcfg, key: Array, algo: str = "adam",
+                quantize_state: bool = True) -> SubspaceState:
     """Classify leaves, build the grouped layout, sample initial
     projections (one batched draw per group), zero moments.
 
@@ -360,7 +360,6 @@ def init(params, tcfg, key: Array, algo: str = "adam",
     zero-size ``(G,)+lead+(0, r)`` placeholder.  Dense (non-grouped)
     slots stay plain fp32 either way: they are norm scales and biases,
     not the footprint."""
-    params = params_of(params)
     layout = build_layout(params, tcfg, algo=algo,
                           quantize_state=quantize_state)
     cdt = DTYPES[layout.compute_dtype]
@@ -408,8 +407,6 @@ def group_params(params, layout: SubspaceLayout) -> GroupedParams:
     """Stack each group's member weights into one ``(G,)+lead+(k, n)``
     buffer (ONE stack per group, at init time — the training loop never
     stacks again).  Non-grouped leaves pass through untouched."""
-    if isinstance(params, GroupedParams):
-        return params
     flat_p, treedef = jax.tree.flatten(params)
     return GroupedParams(
         dense=tuple(flat_p[i] for i in layout.dense_idx),
@@ -423,9 +420,10 @@ def params_of(params):
 
     For a :class:`GroupedParams` the grouped leaves are *slices* of the
     stacked buffers (lazy under jit/eval_shape — no copy until a consumer
-    materialises them); raw trees pass through unchanged.  This is the
-    ungroup point for API boundaries (checkpoint templates, serving,
-    introspection) — the training loop itself never calls it.
+    materialises them); raw trees (AdamW's params) pass through
+    unchanged.  This is the ungroup point for API boundaries (checkpoint
+    templates, serving, introspection) — the training loop itself never
+    calls it.
     """
     if not isinstance(params, GroupedParams):
         return params
@@ -439,14 +437,16 @@ def params_of(params):
     return jax.tree.unflatten(params.treedef, out)
 
 
-def init_grouped(params, tcfg, key: Array, algo: str = "adam"):
-    """One-call trainer entry: classify leaves, build the grouped state AND
-    the grouped master weights from the same layout.
+def init(params, tcfg, key: Array,
+         algo: str = "adam") -> Tuple[GroupedParams, SubspaceState]:
+    """The trainer entry: classify the leaves of the model tree ``params``,
+    build the grouped state AND the grouped master weights from the same
+    layout.
 
-    Returns ``(grouped_params, state)`` — the canonical in-training
-    representation pair (both structure-of-arrays, both donatable).
+    Returns ``(grouped_params, state)`` — the in-training representation
+    pair (both structure-of-arrays, both donatable).
     """
-    state = init(params, tcfg, key, algo=algo)
+    state = _init_state(params, tcfg, key, algo=algo)
     return group_params(params, state.layout), state
 
 
@@ -454,20 +454,11 @@ def init_grouped(params, tcfg, key: Array, algo: str = "adam"):
 # Packing and trainable extraction
 # ---------------------------------------------------------------------------
 
-def _is_slot(x):
-    return isinstance(x, (DenseSlot, LowRankSlot, GroupedLowRankSlot))
-
-
 def trainable_of(params, state: SubspaceState) -> Trainable:
     """The differentiation tree: the stacked B buffer of every group plus
-    the raw W of every dense leaf.  No copies — leaves are references."""
-    if isinstance(params, GroupedParams):
-        return Trainable(dense=params.dense,
-                         groups=tuple(g.b for g in state.groups))
-    flat_p = jax.tree.leaves(params)
-    return Trainable(
-        dense=tuple(flat_p[i] for i in state.layout.dense_idx),
-        groups=tuple(g.b for g in state.groups))
+    the W of every dense leaf.  No copies — leaves are references."""
+    return Trainable(dense=params.dense,
+                     groups=tuple(g.b for g in state.groups))
 
 
 def packed_params(params, state: SubspaceState, trainable: Trainable,
@@ -477,9 +468,9 @@ def packed_params(params, state: SubspaceState, trainable: Trainable,
 
     ``B[g]`` / ``V[g]`` / ``W[g]`` are *slices* of the group's stacked
     buffer (one cast per group, then static-index slices) — under jit
-    these alias the donated group buffer instead of copying it.  With
-    grouped master weights the base ``w`` of each LRPack is a slice of the
-    stacked weight buffer the same way.
+    these alias the donated group buffer instead of copying it.  The base
+    ``w`` of each LRPack is a slice of the stacked weight buffer the same
+    way.
 
     ``dtype`` is the compute dtype of the packed views: all three pack
     members (W, B, V) are cast to it so the fused forward/backward reads
@@ -488,23 +479,16 @@ def packed_params(params, state: SubspaceState, trainable: Trainable,
     read-side view, autodiff routes the B cotangent back up to fp32).
     """
     cast = (lambda x: x.astype(dtype)) if dtype else (lambda x: x)
-    grouped = isinstance(params, GroupedParams)
-    if grouped:
-        treedef = params.treedef
-        out: list = [None] * state.layout.n_leaves
-    else:
-        flat_p, treedef = jax.tree.flatten(params)
-        out = list(flat_p)
+    out: list = [None] * state.layout.n_leaves
     for di, i in enumerate(state.layout.dense_idx):
         out[i] = trainable.dense[di]
     for g, spec in enumerate(state.layout.groups):
         tb = cast(trainable.groups[g])
         tv = cast(state.groups[g].proj)
-        wg = cast(params.groups[g]) if grouped else None
+        wg = cast(params.groups[g])
         for j, i in enumerate(spec.leaf_idx):
-            out[i] = LRPack(wg[j] if grouped else cast(flat_p[i]),
-                            tb[j], tv[j])
-    return jax.tree.unflatten(treedef, out)
+            out[i] = LRPack(wg[j], tb[j], tv[j])
+    return jax.tree.unflatten(params.treedef, out)
 
 
 def leaf_slots(state: SubspaceState) -> list:
@@ -591,22 +575,15 @@ def inner_update(grads: Trainable, trainable: Trainable, params,
 
     Every group's pre-stacked B/m/v feeds ONE batched ``subspace_adam``
     call through the kernel dispatch layer (the Pallas fused-Adam kernel on
-    TPU) — no per-leaf stack/gather anywhere on this path.  ``params`` may
-    be the model tree or a :class:`GroupedParams`; grouped master weights
-    stay stacked (and untouched — they only move at the outer merge).
+    TPU) — no per-leaf stack/gather anywhere on this path.  The grouped
+    master weights stay stacked (and untouched — they only move at the
+    outer merge).
     """
     grads, gn = clip_by_global_norm(grads, tcfg.grad_clip)
     step = state.step + 1
     stepf = step.astype(jnp.float32)
     bc1 = 1.0 - tcfg.beta1 ** stepf
     bc2 = 1.0 - tcfg.beta2 ** stepf
-
-    grouped = isinstance(params, GroupedParams)
-    if grouped:
-        dense_w = params.dense
-    else:
-        flat_p, pdef = jax.tree.flatten(params)
-        dense_w = tuple(flat_p[i] for i in state.layout.dense_idx)
 
     layout = state.layout
     lion = layout.algo == "lion"
@@ -615,7 +592,7 @@ def inner_update(grads: Trainable, trainable: Trainable, params,
 
     # -- dense leaves: plain elementwise math (XLA fuses the chain) --------
     new_dense_w, new_dense = [], []
-    for di, w in enumerate(dense_w):
+    for di, w in enumerate(params.dense):
         if lion:
             new_p, slot = _dense_lion(state.dense[di], w, grads.dense[di],
                                       lr=lr, tcfg=tcfg)
@@ -679,16 +656,10 @@ def inner_update(grads: Trainable, trainable: Trainable, params,
             energy=_group_energy_update(slot, g32)))
         new_tgroups.append(nb)
 
-    if grouped:
-        new_params = GroupedParams(dense=tuple(new_dense_w),
-                                   groups=params.groups,
-                                   layout=params.layout,
-                                   treedef=params.treedef)
-    else:
-        new_flat_p = list(flat_p)
-        for di, i in enumerate(state.layout.dense_idx):
-            new_flat_p[i] = new_dense_w[di]
-        new_params = jax.tree.unflatten(pdef, new_flat_p)
+    new_params = GroupedParams(dense=tuple(new_dense_w),
+                               groups=params.groups,
+                               layout=params.layout,
+                               treedef=params.treedef)
     new_trainable = Trainable(
         dense=tuple(new_dense_w),
         groups=tuple(new_tgroups))
@@ -706,13 +677,11 @@ def inner_update(grads: Trainable, trainable: Trainable, params,
 def outer_merge_resample(params, state: SubspaceState, tcfg):
     """W += V B^T (fp32 accumulate), resample V, zero B (+ moments).
 
-    With grouped master weights (:class:`GroupedParams`) this is the pure
-    batched form: per group ONE ``lowrank_merge`` over the already-stacked
-    ``(G, ..., k, n)`` weight buffer and ONE batched sampler draw — zero
+    The pure batched form: per group ONE ``lowrank_merge`` over the
+    already-stacked ``(G, ..., k, n)`` weight buffer of the
+    :class:`GroupedParams` and ONE batched sampler draw — zero
     stack/unstack anywhere (asserted by jaxpr inspection in
-    tests/test_grouped_params.py).  On a raw model tree the member weights
-    are stacked/unstacked around the same batched merge (the per-leaf-
-    weights compat path; identical key schedule, bit-identical results).
+    tests/test_grouped_params.py).
 
     Runs fully sharded: W/V/B share one G-axis split per group (the
     :func:`~repro.sharding.rules.state_pspecs` invariant), so the merge
@@ -722,16 +691,11 @@ def outer_merge_resample(params, state: SubspaceState, tcfg):
     under a traced ``lax.cond`` (``train.steps.fuse_outer_into_inner``).
     """
     nkey, skey = jax.random.split(state.key)
-    grouped = isinstance(params, GroupedParams)
-    if not grouped:
-        flat_p, pdef = jax.tree.flatten(params)
-        new_flat_p = list(flat_p)
     gkeys = jax.random.split(skey, max(len(state.groups), 1))
     sr_master = state.layout.master_dtype == "bfloat16"
     new_wgroups, new_groups = [], []
     for g, (spec, slot) in enumerate(zip(state.layout.groups, state.groups)):
-        ws = params.groups[g] if grouped else \
-            jnp.stack([flat_p[i] for i in spec.leaf_idx])
+        ws = params.groups[g]
         if sr_master and jnp.dtype(ws.dtype) == jnp.bfloat16:
             # merging into narrow stored weights: stochastic rounding
             # keeps the once-per-K accumulate unbiased across outer cycles
@@ -739,11 +703,7 @@ def outer_merge_resample(params, state: SubspaceState, tcfg):
             merged = dispatch.lowrank_merge_sr(ws, slot.proj, slot.b, mbits)
         else:
             merged = dispatch.lowrank_merge(ws, slot.proj, slot.b)
-        if grouped:
-            new_wgroups.append(merged)
-        else:
-            for j, i in enumerate(spec.leaf_idx):
-                new_flat_p[i] = merged[j]
+        new_wgroups.append(merged)
         proj = _sample_proj_group(tcfg.sampler, gkeys[g], spec,
                                   len(spec.leaf_idx), tcfg.c, slot.energy,
                                   dtype=slot.proj.dtype)
@@ -758,16 +718,13 @@ def outer_merge_resample(params, state: SubspaceState, tcfg):
                               step=state.step,
                               outer_step=state.outer_step + 1, key=nkey,
                               layout=state.layout)
-    if grouped:
-        return GroupedParams(dense=params.dense, groups=tuple(new_wgroups),
-                             layout=params.layout,
-                             treedef=params.treedef), new_state
-    return jax.tree.unflatten(pdef, new_flat_p), new_state
+    return GroupedParams(dense=params.dense, groups=tuple(new_wgroups),
+                         layout=params.layout,
+                         treedef=params.treedef), new_state
 
 
 # ---------------------------------------------------------------------------
-# Per-leaf reference implementations (tests + the "ungrouped" benchmark
-# baseline).  These reproduce the pre-grouped layout's behaviour: a Python
+# Per-leaf reference implementations (tests).  These reproduce the pre-grouped layout's behaviour: a Python
 # loop over leaves, per-leaf kernel calls, per-leaf key splits.  NOT the
 # hot path.  They consume the raw model tree only — ungroup with
 # :func:`params_of` first when comparing against a GroupedParams run.

@@ -13,11 +13,12 @@ Table 2.
 ``vanilla=True`` degrades to full-space ZO (Vanilla LR baseline): every leaf
 is perturbed with a full-shape Gaussian.
 
-``params`` may be the model tree or grouped master weights
-(:class:`repro.optim.subspace.GroupedParams`): noise, perturbation and the
-Adam update all act on the *trainable* buffers (stacked B per group), so
-the grouped layout flows through untouched — packing slices the stacked
-weight buffers lazily, exactly like the backprop path.
+``params`` are the grouped master weights
+(:class:`repro.optim.subspace.GroupedParams`, from ``subspace.init``):
+noise, perturbation and the Adam update all act on the *trainable* buffers
+(stacked B per group), so the grouped layout flows through untouched —
+packing slices the stacked weight buffers lazily, exactly like the
+backprop path.
 """
 from __future__ import annotations
 

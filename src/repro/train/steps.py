@@ -94,9 +94,9 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
     pdt = _pack_dtype(cfg, tcfg)
 
     def train_step(params, opt_state: subspace.SubspaceState, batch):
-        # ``params`` is either the model tree or (the Trainer's canonical
-        # in-training representation) a ``subspace.GroupedParams`` whose
-        # stacked weight buffers packed_params slices lazily per leaf.
+        # ``params`` is a ``subspace.GroupedParams`` (from
+        # ``subspace.init``) whose stacked weight buffers packed_params
+        # slices lazily per leaf.
         lr = _lr_at(tcfg, opt_state.step)
         trainable = subspace.trainable_of(params, opt_state)
 

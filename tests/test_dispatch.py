@@ -284,7 +284,7 @@ def _tiny_state():
                        grad_clip=0.0, schedule="constant")
     params = {"w1": _arr((16, 12)), "w2": _arr((16, 12)),
               "w3": _arr((12, 10)), "bias": _arr((12,))}
-    state = subspace.init(params, tcfg, jax.random.key(0))
+    params, state = subspace.init(params, tcfg, jax.random.key(0))
     return tcfg, params, state
 
 
@@ -322,8 +322,8 @@ def test_inner_update_matches_ref_adam(monkeypatch):
         grads, trainable, params, state, lr=1e-2, tcfg=tcfg)
     old = subspace.slots_by_path(params, state)
     new = subspace.slots_by_path(params, new_s)
-    paths = [subspace._path_str(p) for p, _ in
-             jax.tree_util.tree_flatten_with_path(params)[0]]
+    paths = [subspace._path_str(p) for p, _ in jax.tree_util
+             .tree_flatten_with_path(subspace.params_of(params))[0]]
 
     def member_grad(name):
         """The member's gradient row inside its group's stacked buffer."""
@@ -362,6 +362,8 @@ def test_outer_merge_routes_through_dispatch(monkeypatch):
                                            lr=1e-2, tcfg=tcfg)
     new_params, new_state = subspace.outer_merge_resample(params, state,
                                                           tcfg)
+    params = subspace.params_of(params)
+    new_params = subspace.params_of(new_params)
     # one BATCHED merge per group ({w1, w2} share a group; w3 has its own)
     assert len(calls) == len(state.groups) == 2
     # merge really applied: W' = W + V B^T

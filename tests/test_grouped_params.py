@@ -3,9 +3,10 @@
   * the outer step on ``GroupedParams`` is a pure batched merge: its jaxpr
     contains ZERO concatenates over float leaves (no weight stack/unstack)
     and no gathers beyond the batched-QR sign fix;
-  * the grouped-weights training loop bit-matches the per-leaf-weights
-    path for all four samplers over >= 3 outer cycles (same key schedule),
-    and the per-leaf *state* reference (`inner_update_ref`) within cycles;
+  * the grouped training loop matches the per-leaf references
+    (`inner_update_ref`, `outer_merge_resample_ref` on the model tree) for
+    all four samplers over 3 outer cycles; LowRank-LR and GaLore steps
+    match per-leaf estimates written out in the tests;
   * grouped weights checkpoint natively and round-trip; legacy per-leaf
     weight checkpoints migrate on restore (CRC-checked, drift-rejecting);
   * the Trainer carries GroupedParams through both jitted steps and
@@ -78,9 +79,9 @@ def _assert_trees_equal(a, b, **tol):
 def test_group_params_roundtrip_and_idempotence():
     tcfg = _tcfg()
     params = _params()
-    gp, state = subspace.init_grouped(params, tcfg, jax.random.key(0))
+    gp, state = subspace.init(params, tcfg, jax.random.key(0))
     assert isinstance(gp, subspace.GroupedParams)
-    assert subspace.group_params(gp, state.layout) is gp  # idempotent
+    _assert_trees_equal(subspace.group_params(params, state.layout), gp)
     _assert_trees_equal(subspace.params_of(gp), params)
     assert subspace.params_of(params) is params           # raw passthrough
     # every group buffer is (G,) + member shape
@@ -91,7 +92,7 @@ def test_group_params_roundtrip_and_idempotence():
 def test_packed_params_slices_grouped_weights():
     tcfg = _tcfg()
     params = _params()
-    gp, state = subspace.init_grouped(params, tcfg, jax.random.key(0))
+    gp, state = subspace.init(params, tcfg, jax.random.key(0))
     trainable = subspace.trainable_of(gp, state)
     packed = subspace.packed_params(gp, state, trainable)
     for name in ("w1", "w2", "w3", "experts", "scan"):
@@ -104,7 +105,7 @@ def test_packed_params_slices_grouped_weights():
 # Jaxpr inspection: the grouped outer step never stacks/unstacks weights
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("sampler", ["stiefel", "dependent_diag"])
+@pytest.mark.parametrize("sampler", SAMPLERS)
 def test_grouped_outer_jaxpr_has_no_weight_stack_or_gather(sampler):
     """Acceptance: the jitted outer step on GroupedParams contains no
     per-leaf concatenate/gather on weight leaves: no concatenate whose
@@ -114,7 +115,7 @@ def test_grouped_outer_jaxpr_has_no_weight_stack_or_gather(sampler):
     bookkeeping (dependent_diag; stiefel has zero).  Gathers only from the
     batched QR sign-fix diagonal."""
     tcfg = _tcfg(sampler)
-    gp, state = subspace.init_grouped(_params(), tcfg, jax.random.key(0))
+    gp, state = subspace.init(_params(), tcfg, jax.random.key(0))
     jaxpr = jax.make_jaxpr(
         lambda p, s: subspace.outer_merge_resample(p, s, tcfg))(gp, state)
     eqns = _prims(jaxpr)
@@ -138,11 +139,15 @@ def test_grouped_outer_jaxpr_has_no_weight_stack_or_gather(sampler):
         # gathers only the batched QR sign-fix diagonal
         assert not any(e.primitive.name == "concatenate" and jnp.issubdtype(
             e.outvars[0].aval.dtype, jnp.floating) for e in eqns)
-        for e in eqns:
-            if e.primitive.name == "gather":
-                op = e.invars[0].aval.shape
-                assert len(op) == 3 and op[-1] == op[-2], \
-                    f"unexpected gather over {op} in grouped outer step"
+        gathers = [e for e in eqns if e.primitive.name == "gather"]
+        for e in gathers:
+            op = e.invars[0].aval.shape
+            assert len(op) == 3 and op[-1] == op[-2], \
+                f"unexpected gather over {op} in grouped outer step"
+        # ONE sign-fix gather per group at most — never a per-leaf one
+        assert len(gathers) <= len(state.layout.groups)
+
+
 def test_grouped_inner_jaxpr_has_no_stack_or_gather(monkeypatch):
     """The inner step stays gather/concat-free with grouped weights too.
 
@@ -150,7 +155,7 @@ def test_grouped_inner_jaxpr_has_no_stack_or_gather(monkeypatch):
     Pallas pad-to-tile wrappers slice/pad inside the op by design)."""
     monkeypatch.setenv("REPRO_KERNEL_DISPATCH", "xla")
     tcfg = _tcfg("stiefel")
-    gp, state = subspace.init_grouped(_params(), tcfg, jax.random.key(0))
+    gp, state = subspace.init(_params(), tcfg, jax.random.key(0))
     trainable = subspace.trainable_of(gp, state)
     grads = _grads_like(trainable, 1)
     jaxpr = jax.make_jaxpr(
@@ -164,36 +169,42 @@ def test_grouped_inner_jaxpr_has_no_stack_or_gather(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# Equivalence: grouped weights == per-leaf weights over >= 3 outer cycles
+# Equivalence: the grouped path == per-leaf references over 3 outer cycles
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("sampler", SAMPLERS)
 def test_grouped_loop_bitmatches_per_leaf_weights(sampler):
     """Full training loop (lazy_k inner steps + outer merge+resample, 3
-    outer cycles): the GroupedParams path and the raw-tree (per-leaf
-    weights) path produce bit-identical params, trainables and state —
-    same batched kernels, same key schedule, no tolerance needed."""
+    outer cycles): every grouped inner step matches ``inner_update_ref`` and
+    every grouped outer merge's weights match ``outer_merge_resample_ref``,
+    both run on the model tree ``params_of(...)`` from the same state (fp32
+    tolerance: per-leaf kernel calls; the resampled V differs only by key
+    schedule, so the loop carries on from the grouped result)."""
     tcfg = _tcfg(sampler)
-    tree = _params()
-    gp, state_g = subspace.init_grouped(tree, tcfg, jax.random.key(0))
-    state_t = subspace.init(tree, tcfg, jax.random.key(0))
+    gp, state = subspace.init(_params(), tcfg, jax.random.key(0))
     for cycle in range(3):
         for it in range(tcfg.lazy_k):
-            tr_g = subspace.trainable_of(gp, state_g)
-            tr_t = subspace.trainable_of(tree, state_t)
-            _assert_trees_equal(tr_g, tr_t)
-            grads = _grads_like(tr_g, 100 * cycle + it)
-            gp, _, state_g, gn_g = subspace.inner_update(
-                grads, tr_g, gp, state_g, lr=1e-2, tcfg=tcfg)
-            tree, _, state_t, gn_t = subspace.inner_update(
-                grads, tr_t, tree, state_t, lr=1e-2, tcfg=tcfg)
-            assert float(gn_g) == float(gn_t)
-        gp, state_g = subspace.outer_merge_resample(gp, state_g, tcfg)
-        tree, state_t = subspace.outer_merge_resample(tree, state_t, tcfg)
-        _assert_trees_equal(subspace.params_of(gp), tree)
-        _assert_trees_equal((state_g.dense, state_g.groups),
-                            (state_t.dense, state_t.groups))
-    assert int(state_g.outer_step) == 3
+            tr = subspace.trainable_of(gp, state)
+            grads = _grads_like(tr, 100 * cycle + it)
+            tree_r, tr_r, state_r, gn_r = subspace.inner_update_ref(
+                grads, tr, subspace.params_of(gp), state, lr=1e-2,
+                tcfg=tcfg)
+            gp, tr, state, gn = subspace.inner_update(
+                grads, tr, gp, state, lr=1e-2, tcfg=tcfg)
+            np.testing.assert_allclose(float(gn), float(gn_r), rtol=1e-6)
+            _assert_trees_equal(subspace.params_of(gp), tree_r,
+                                rtol=1e-6, atol=1e-7)
+            _assert_trees_equal((tr, state.dense, state.groups),
+                                (tr_r, state_r.dense, state_r.groups),
+                                rtol=1e-6, atol=1e-7)
+        tree_r, _ = subspace.outer_merge_resample_ref(
+            subspace.params_of(gp), state, tcfg)
+        gp, state = subspace.outer_merge_resample(gp, state, tcfg)
+        _assert_trees_equal(subspace.params_of(gp), tree_r,
+                            rtol=1e-6, atol=1e-6)
+        for g in state.groups:
+            assert float(jnp.abs(g.b).max()) == 0.0
+    assert int(state.outer_step) == 3
 
 
 @pytest.mark.parametrize("sampler", SAMPLERS)
@@ -203,15 +214,13 @@ def test_grouped_matches_per_leaf_state_reference(sampler):
     grouped outer's merged weights == outer_merge_resample_ref's (the
     resampled V differs only by key schedule)."""
     tcfg = _tcfg(sampler)
-    tree = _params()
-    gp, state = subspace.init_grouped(tree, tcfg, jax.random.key(0))
-    state_t = subspace.init(tree, tcfg, jax.random.key(0))
+    gp, state = subspace.init(_params(), tcfg, jax.random.key(0))
     trainable = subspace.trainable_of(gp, state)
     grads = _grads_like(trainable, 7)
+    tree_r, _, state_r, _ = subspace.inner_update_ref(
+        grads, trainable, subspace.params_of(gp), state, lr=1e-2, tcfg=tcfg)
     gp, _, state, _ = subspace.inner_update(
         grads, trainable, gp, state, lr=1e-2, tcfg=tcfg)
-    tree_r, _, state_r, _ = subspace.inner_update_ref(
-        grads, trainable, tree, state_t, lr=1e-2, tcfg=tcfg)
     _assert_trees_equal(subspace.params_of(gp), tree_r,
                         rtol=1e-6, atol=1e-7)
     _assert_trees_equal((state.dense, state.groups),
@@ -224,51 +233,126 @@ def test_grouped_matches_per_leaf_state_reference(sampler):
 
 
 def test_zo_step_grouped_matches_tree():
-    """LowRank-LR: noise and the ZO estimate depend only on the state, so
-    the grouped and per-leaf-weights paths stay bit-identical."""
+    """LowRank-LR: the grouped ZO step == a per-leaf antithetic two-point
+    estimate from the same noise (``zo._sample_noise``), written out here
+    leaf by leaf, passed through ``inner_update_ref``."""
+    from repro.models.linear import LRPack, linear
     from repro.optim import zo
     tcfg = _tcfg("stiefel", optimizer="lowrank_lr")
-    tree = _params()
-    gp, state = subspace.init_grouped(tree, tcfg, jax.random.key(0))
-    state_t = subspace.init(tree, tcfg, jax.random.key(0))
+    gp, state = subspace.init(_params(), tcfg, jax.random.key(0))
 
     def loss_fn(packed, batch):
-        from repro.models.linear import linear
         y = linear(batch, packed["w1"])
         return jnp.mean(y * y)
 
     batch = jnp.asarray(RNG.normal(size=(4, 16)), jnp.float32)
     key = jax.random.key(3)
-    l_g, gp2, sg, gn_g = zo.zo_inner_step(
+    l_g, gp2, s_g, gn_g = zo.zo_inner_step(
         loss_fn, gp, state, batch, key, lr=1e-2, tcfg=tcfg)
-    l_t, tree2, st, gn_t = zo.zo_inner_step(
-        loss_fn, tree, state_t, batch, key, lr=1e-2, tcfg=tcfg)
-    assert float(l_g) == float(l_t)
-    _assert_trees_equal(subspace.params_of(gp2), tree2)
+
+    # per-leaf reference: W + V (B + s·σ·Z)ᵀ at every low-rank leaf,
+    # W + s·σ·z at every dense leaf
+    tree = subspace.params_of(gp)
+    flat, treedef = jax.tree.flatten(tree)
+    trainable = subspace.trainable_of(gp, state)
+    noise = zo._sample_noise(state, key)
+    sigma = tcfg.zo_sigma
+
+    def perturbed(sign):
+        out = list(flat)
+        for di, i in enumerate(state.layout.dense_idx):
+            out[i] = flat[i] + sign * sigma * noise.dense[di]
+        for g, spec in enumerate(state.layout.groups):
+            for j, i in enumerate(spec.leaf_idx):
+                b = trainable.groups[g][j] + sign * sigma * noise.groups[g][j]
+                out[i] = LRPack(flat[i], b, state.groups[g].proj[j])
+        return jax.tree.unflatten(treedef, out)
+
+    fp = loss_fn(perturbed(+1.0), batch)
+    fm = loss_fn(perturbed(-1.0), batch)
+    coeff = (fp - fm) / (2.0 * sigma)
+    grads = jax.tree.map(lambda z: coeff * z, noise)
+    tree_r, _, s_r, gn_r = subspace.inner_update_ref(
+        grads, trainable, tree, state, lr=1e-2, tcfg=tcfg)
+    np.testing.assert_allclose(float(l_g), float(0.5 * (fp + fm)),
+                               rtol=1e-6)
+    np.testing.assert_allclose(float(gn_g), float(gn_r), rtol=1e-6)
+    _assert_trees_equal(subspace.params_of(gp2), tree_r,
+                        rtol=1e-6, atol=1e-7)
+    _assert_trees_equal((s_g.dense, s_g.groups), (s_r.dense, s_r.groups),
+                        rtol=1e-6, atol=1e-7)
+
+
+def _galore_ref(tree, grads, slots, step, *, lr, tcfg, refresh, layout):
+    """Per-leaf GaLore step: R = Uᵀ G, Adam on R, W -= lr·(U Δ + wd·W);
+    plain Adam on the dense leaves.  ``slots``: {leaf index: (U, m, v)}."""
+    gn = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree.leaves(grads)))
+    scale = jnp.minimum(1.0, tcfg.grad_clip / gn)
+    flat, treedef = jax.tree.flatten(tree)
+    flat_g = [g * scale for g in jax.tree.leaves(grads)]
+    b1, b2, eps = tcfg.beta1, tcfg.beta2, tcfg.eps
+    bc1, bc2 = 1.0 - b1 ** step, 1.0 - b2 ** step
+    out, new_slots = list(flat), {}
+    for i, (w, g) in enumerate(zip(flat, flat_g)):
+        u, m, v = slots[i]
+        if i in layout.dense_idx:
+            m = b1 * m + (1 - b1) * g
+            v = b2 * v + (1 - b2) * g * g
+            out[i] = w - lr * (m / bc1) / (jnp.sqrt(v / bc2) + eps)
+        else:
+            if refresh:                       # top-r left singular vectors
+                gram = jnp.einsum("...kn,...ln->...kl", g, g)
+                u = jnp.linalg.eigh(gram)[1][..., -u.shape[-1]:]
+            r = jnp.einsum("...kr,...kn->...nr", u, g)
+            m = b1 * m + (1 - b1) * r
+            v = b2 * v + (1 - b2) * r * r
+            delta = (m / bc1) / (jnp.sqrt(v / bc2) + eps)
+            out[i] = w - lr * (jnp.einsum("...kr,...nr->...kn", u, delta)
+                               + tcfg.weight_decay * w)
+        new_slots[i] = (u, m, v)
+    return jax.tree.unflatten(treedef, out), new_slots
 
 
 def test_galore_update_grouped_matches_tree():
-    """GaLore's per-step weight write on stacked buffers == the per-leaf
-    stack/unstack path, for both refresh branches."""
+    """GaLore's per-step weight write on stacked buffers == a per-leaf
+    GaLore update written out here, for both refresh branches.  U is
+    compared as the projector U Uᵀ and m as U mᵀ (an eigenvector's sign
+    is free; the weight update does not depend on it)."""
     from repro.optim import galore
     tcfg = _tcfg("stiefel", weight_decay=0.01)
     tree = _params()
-    gp, state = galore.init_grouped(tree, tcfg, jax.random.key(0))
-    state_t = galore.init(tree, tcfg, jax.random.key(0))
+    gp, state = galore.init(tree, tcfg, jax.random.key(0))
+    layout = state.layout
     rng = np.random.default_rng(5)
     flat_g = [jnp.asarray(rng.normal(size=x.shape), jnp.float32)
               for x in jax.tree.leaves(tree)]
     g_tree = jax.tree.unflatten(jax.tree.structure(tree), flat_g)
-    g_gp = subspace.group_params(g_tree, state.layout)
-    for refresh in (True, False):
-        p_g, s_g = galore.update(g_gp, gp, state, lr=1e-2, tcfg=tcfg,
-                                 refresh=refresh)
-        p_t, s_t = galore.update(g_tree, tree, state_t, lr=1e-2, tcfg=tcfg,
-                                 refresh=refresh)
-        _assert_trees_equal(subspace.params_of(p_g), p_t)
-        _assert_trees_equal((s_g.dense, s_g.groups),
-                            (s_t.dense, s_t.groups))
-        gp, state, tree, state_t = p_g, s_g, p_t, s_t
+    g_gp = subspace.group_params(g_tree, layout)
+    slots = {}
+    for di, i in enumerate(layout.dense_idx):
+        slots[i] = (None, state.dense[di].m, state.dense[di].v)
+    for spec, slot in zip(layout.groups, state.groups):
+        for j, i in enumerate(spec.leaf_idx):
+            slots[i] = (slot.proj[j], slot.m[j], slot.v[j])
+    for step, refresh in enumerate((True, False), start=1):
+        gp, state = galore.update(g_gp, gp, state, lr=1e-2, tcfg=tcfg,
+                                  refresh=refresh)
+        tree, slots = _galore_ref(tree, g_tree, slots, step, lr=1e-2,
+                                  tcfg=tcfg, refresh=refresh, layout=layout)
+        # fp32 through two eigensolver calls on differently-formed grams
+        tol = dict(rtol=1e-5, atol=1e-6)
+        _assert_trees_equal(subspace.params_of(gp), tree, **tol)
+        for di, i in enumerate(layout.dense_idx):
+            _assert_trees_equal(state.dense[di], slots[i][1:], **tol)
+        for spec, slot in zip(layout.groups, state.groups):
+            for j, i in enumerate(spec.leaf_idx):
+                u, m, v = slots[i]
+                uu = lambda x: jnp.einsum("...kr,...lr->...kl", x, x)
+                um = lambda x, y: jnp.einsum("...kr,...nr->...kn", x, y)
+                _assert_trees_equal(uu(slot.proj[j]), uu(u), **tol)
+                _assert_trees_equal(um(slot.proj[j], slot.m[j]), um(u, m),
+                                    **tol)
+                _assert_trees_equal(slot.v[j], v, **tol)
 
 
 # ---------------------------------------------------------------------------
@@ -283,7 +367,7 @@ def _state_arrays(state):
 @pytest.mark.parametrize("sampler", ["stiefel", "dependent_diag"])
 def test_grouped_weights_checkpoint_roundtrip(tmp_path, sampler):
     tcfg = _tcfg(sampler)
-    gp, state = subspace.init_grouped(_params(), tcfg, jax.random.key(0))
+    gp, state = subspace.init(_params(), tcfg, jax.random.key(0))
     trainable = subspace.trainable_of(gp, state)
     gp, _, state, _ = subspace.inner_update(
         _grads_like(trainable, 3), trainable, gp, state, lr=1e-2, tcfg=tcfg)
@@ -306,7 +390,7 @@ def test_legacy_per_leaf_weight_checkpoint_migrates(tmp_path):
     through the migration."""
     tcfg = _tcfg("stiefel")
     tree = _params()
-    gp, state = subspace.init_grouped(tree, tcfg, jax.random.key(0))
+    gp, state = subspace.init(tree, tcfg, jax.random.key(0))
     wd = str(tmp_path / "legacy_w")
     ckpt.save(wd, 4, {"params": tree, "opt": state})   # legacy layout
     restored, manifest = ckpt.restore(wd, 4, {"params": gp, "opt": state})
@@ -330,23 +414,23 @@ def test_legacy_weight_migration_rejects_layout_drift(tmp_path):
     the migration itself (before any state record is even considered)."""
     tcfg = _tcfg("stiefel")
     tree = _params()
-    _, state = subspace.init_grouped(tree, tcfg, jax.random.key(0))
+    _, state = subspace.init(tree, tcfg, jax.random.key(0))
     wd = str(tmp_path / "drift_w")
     ckpt.save(wd, 1, {"params": tree, "opt": state})
     # (a) same leaf count, different member shape -> shape check fires
     tree_w = dict(tree, w1=jnp.zeros((16, 11), jnp.float32))
-    gp_w, state_w = subspace.init_grouped(tree_w, tcfg, jax.random.key(0))
+    gp_w, state_w = subspace.init(tree_w, tcfg, jax.random.key(0))
     with pytest.raises(IOError, match="drift|expects"):
         ckpt.restore(wd, 1, {"params": gp_w, "opt": state_w})
     # (b) extra leaf -> leaf-count check fires
     tree_n = dict(tree, extra=jnp.zeros((4,), jnp.float32))
-    gp_n, state_n = subspace.init_grouped(tree_n, tcfg, jax.random.key(0))
+    gp_n, state_n = subspace.init(tree_n, tcfg, jax.random.key(0))
     with pytest.raises(IOError, match="weight leaves"):
         ckpt.restore(wd, 1, {"params": gp_n, "opt": state_n})
     # grouping-only drift (shapes intact) migrates the weights fine but the
     # STATE template still fails loudly -> no silent wrong-slot mapping
     d_tcfg = _tcfg("stiefel", min_dim_for_lowrank=11)  # w3 flips to dense
-    gp_d, state_d = subspace.init_grouped(tree, d_tcfg, jax.random.key(0))
+    gp_d, state_d = subspace.init(tree, d_tcfg, jax.random.key(0))
     assert gp_d.layout != state.layout
     with pytest.raises(IOError):
         ckpt.restore(wd, 1, {"params": gp_d, "opt": state_d})

@@ -1,9 +1,8 @@
 """Structure-of-arrays subspace state (ISSUE-2 acceptance criteria).
 
   * ``inner_update``'s jaxpr contains NO per-leaf ``concatenate``/``gather``
-    over B leaves — the grouped layout feeds the batched kernels natively;
-  * ``outer_merge_resample`` stacks only the weights (one concatenate per
-    multi-member group), never the subspace state;
+    over B leaves — the grouped layout feeds the batched kernels natively
+    (the outer step's jaxpr is checked in tests/test_grouped_params.py);
   * grouped results match the per-leaf reference implementation bit-for-bit
     (fp32 tolerance) for all four samplers, including stacked-expert
     (3-D/4-D) leaves;
@@ -70,8 +69,7 @@ def test_inner_update_jaxpr_has_no_stack_or_gather(sampler, monkeypatch):
     # Pallas pad-to-tile wrappers legitimately slice/pad inside the op.
     monkeypatch.setenv("REPRO_KERNEL_DISPATCH", "xla")
     tcfg = _tcfg(sampler)
-    params = _params()
-    state = subspace.init(params, tcfg, jax.random.key(0))
+    params, state = subspace.init(_params(), tcfg, jax.random.key(0))
     trainable = subspace.trainable_of(params, state)
     grads = _grads(trainable)
     jaxpr = jax.make_jaxpr(
@@ -84,38 +82,6 @@ def test_inner_update_jaxpr_has_no_stack_or_gather(sampler, monkeypatch):
     assert not bad, f"inner_update emits per-leaf stack/gather work: {bad}"
 
 
-def test_outer_step_stacks_only_weights():
-    """The only concatenates in the outer step are the per-group weight
-    stacks — never over B/m/v/V (state stays stacked), never per leaf."""
-    tcfg = _tcfg("stiefel")
-    params = _params()
-    state = subspace.init(params, tcfg, jax.random.key(0))
-    jaxpr = jax.make_jaxpr(
-        lambda p, s: subspace.outer_merge_resample(p, s, tcfg))(params, state)
-    eqns = _prims(jaxpr)
-    # gathers: only the batched QR sign-fix diagonal, (batch, r, r) ->
-    # (batch, r), ONE per group — never a per-leaf state gather
-    gathers = [e for e in eqns if e.primitive.name == "gather"]
-    for e in gathers:
-        op = e.invars[0].aval.shape
-        assert len(op) == 3 and op[-1] == op[-2], \
-            f"unexpected gather over {op} in outer step"
-    assert len(gathers) <= len(state.layout.groups)
-    # float concatenates: only the per-group weight stacks (uint32 ones are
-    # PRNG key-split bookkeeping, constant-size per group)
-    concats = [e for e in eqns if e.primitive.name == "concatenate"
-               and e.outvars[0].aval.dtype == jnp.float32]
-    member_shapes = {spec.shape for spec in state.layout.groups}
-    for e in concats:
-        shapes = {tuple(v.aval.shape) for v in e.invars}
-        # every concatenated operand is a (1,)+W-shaped weight slice
-        assert all(s[1:] in member_shapes and s[0] == 1 for s in shapes), \
-            f"non-weight concatenate in outer step: {shapes}"
-    # at most one stack per multi-member group
-    multi = sum(1 for spec in state.layout.groups if len(spec.leaf_idx) > 1)
-    assert len(concats) <= multi
-
-
 # ---------------------------------------------------------------------------
 # Grouped == per-leaf reference, all four samplers, expert-stacked leaves
 # ---------------------------------------------------------------------------
@@ -123,8 +89,7 @@ def test_outer_step_stacks_only_weights():
 @pytest.mark.parametrize("sampler", SAMPLERS)
 def test_grouped_inner_matches_per_leaf_reference(sampler):
     tcfg = _tcfg(sampler)
-    params = _params()
-    state = subspace.init(params, tcfg, jax.random.key(0))
+    params, state = subspace.init(_params(), tcfg, jax.random.key(0))
     # two chained steps so the energy EMA path (dependent_diag) is exercised
     for it in range(2):
         trainable = subspace.trainable_of(params, state)
@@ -132,8 +97,10 @@ def test_grouped_inner_matches_per_leaf_reference(sampler):
         p_a, t_a, s_a, gn_a = subspace.inner_update(
             grads, trainable, params, state, lr=1e-2, tcfg=tcfg)
         p_b, t_b, s_b, gn_b = subspace.inner_update_ref(
-            grads, trainable, params, state, lr=1e-2, tcfg=tcfg)
-        for a, b in zip(jax.tree.leaves(p_a), jax.tree.leaves(p_b)):
+            grads, trainable, subspace.params_of(params), state, lr=1e-2,
+            tcfg=tcfg)
+        for a, b in zip(jax.tree.leaves(subspace.params_of(p_a)),
+                        jax.tree.leaves(p_b)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-6, atol=1e-7)
         for a, b in zip(jax.tree.leaves((t_a, s_a.dense, s_a.groups)),
@@ -148,16 +115,17 @@ def test_grouped_inner_matches_per_leaf_reference(sampler):
 @pytest.mark.parametrize("sampler", SAMPLERS)
 def test_grouped_outer_merge_matches_per_leaf_reference(sampler):
     tcfg = _tcfg(sampler)
-    params = _params()
-    state = subspace.init(params, tcfg, jax.random.key(0))
+    params, state = subspace.init(_params(), tcfg, jax.random.key(0))
     trainable = subspace.trainable_of(params, state)
     grads = _grads(trainable)
     params, _, state, _ = subspace.inner_update(
         grads, trainable, params, state, lr=1e-2, tcfg=tcfg)
     p_a, s_a = subspace.outer_merge_resample(params, state, tcfg)
-    p_b, s_b = subspace.outer_merge_resample_ref(params, state, tcfg)
+    p_b, s_b = subspace.outer_merge_resample_ref(subspace.params_of(params),
+                                                 state, tcfg)
     # merged weights agree (the resampled V differs only by key schedule)
-    for a, b in zip(jax.tree.leaves(p_a), jax.tree.leaves(p_b)):
+    for a, b in zip(jax.tree.leaves(subspace.params_of(p_a)),
+                    jax.tree.leaves(p_b)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-6, atol=1e-6)
     for g_a in s_a.groups:
@@ -168,8 +136,7 @@ def test_batched_stiefel_resample_is_haar_scaled():
     """Every member V drawn by the batched group sampler satisfies the
     Theorem-2 condition V^T V = (c n / r) I_r."""
     tcfg = _tcfg("stiefel")
-    params = _params()
-    state = subspace.init(params, tcfg, jax.random.key(0))
+    params, state = subspace.init(_params(), tcfg, jax.random.key(0))
     _, state2 = subspace.outer_merge_resample(params, state, tcfg)
     for spec, slot in zip(state2.layout.groups, state2.groups):
         k, r = spec.shape[-2], spec.rank
@@ -186,8 +153,7 @@ def test_trainable_and_packed_share_group_buffers():
     """packed_params consumes slices of the stacked trainable, and
     leaf_slots views reassemble exactly the per-leaf state."""
     tcfg = _tcfg("stiefel")
-    params = _params()
-    state = subspace.init(params, tcfg, jax.random.key(0))
+    params, state = subspace.init(_params(), tcfg, jax.random.key(0))
     trainable = subspace.trainable_of(params, state)
     packed = subspace.packed_params(params, state, trainable)
     slots = subspace.slots_by_path(params, state)
@@ -212,8 +178,7 @@ def _state_arrays(state):
 @pytest.mark.parametrize("sampler", ["stiefel", "dependent_diag"])
 def test_grouped_checkpoint_roundtrip(tmp_path, sampler):
     tcfg = _tcfg(sampler)
-    params = _params()
-    state = subspace.init(params, tcfg, jax.random.key(0))
+    params, state = subspace.init(_params(), tcfg, jax.random.key(0))
     trainable = subspace.trainable_of(params, state)
     params, _, state, _ = subspace.inner_update(
         _grads(trainable), trainable, params, state, lr=1e-2, tcfg=tcfg)
@@ -231,11 +196,11 @@ def test_legacy_per_leaf_checkpoint_migrates(tmp_path, sampler):
     """A checkpoint written in the pre-grouped one-slot-per-leaf layout
     restores into the grouped template (stacked back per group)."""
     tcfg = _tcfg(sampler)
-    params = _params()
-    state = subspace.init(params, tcfg, jax.random.key(0))
+    params, state = subspace.init(_params(), tcfg, jax.random.key(0))
     trainable = subspace.trainable_of(params, state)
     params, _, state, _ = subspace.inner_update(
         _grads(trainable), trainable, params, state, lr=1e-2, tcfg=tcfg)
+    params = subspace.params_of(params)
     # materialise the legacy layout: a params-shaped tree of per-leaf slots
     legacy_slots = jax.tree.unflatten(jax.tree.structure(params),
                                       subspace.leaf_slots(state))
@@ -267,7 +232,7 @@ def test_legacy_migration_rejects_config_drift(tmp_path):
     instead of mapping wrong arrays into slots."""
     tcfg = _tcfg("stiefel")
     params = _params()
-    state = subspace.init(params, tcfg, jax.random.key(0))
+    _, state = subspace.init(params, tcfg, jax.random.key(0))
     legacy_slots = jax.tree.unflatten(jax.tree.structure(params),
                                       subspace.leaf_slots(state))
     legacy = {"params": params,
@@ -275,8 +240,9 @@ def test_legacy_migration_rejects_config_drift(tmp_path):
                       "outer_step": state.outer_step, "key": state.key}}
     wd = str(tmp_path / "drift")
     ckpt.save(wd, 1, legacy)
-    drifted = subspace.init(params, _tcfg("stiefel", min_dim_for_lowrank=11),
-                            jax.random.key(0))  # w3 (12,10) flips to dense
+    _, drifted = subspace.init(params,
+                               _tcfg("stiefel", min_dim_for_lowrank=11),
+                               jax.random.key(0))  # w3 (12,10) flips to dense
     assert drifted.layout != state.layout
     with pytest.raises(IOError, match="config drift|expects"):
         ckpt.restore(wd, 1, {"params": params, "opt": drifted})

@@ -122,8 +122,8 @@ def test_grad_accum_matches_single_step():
     t1 = TrainConfig(**base)
     t2 = TrainConfig(**{**base, "grad_accum": 2})
     from repro.models import lm
-    params = lm.init_params(cfg, jax.random.key(0))
-    state = subspace.init(params, t1, jax.random.key(1))
+    params, state = subspace.init(lm.init_params(cfg, jax.random.key(0)),
+                                  t1, jax.random.key(1))
     batch = StatelessLoader("lm", seed=0, batch=8, seq_len=32,
                             vocab=cfg.vocab_size)(0)
     s1 = jax.jit(steps_mod.make_train_step(cfg, t1))
@@ -148,8 +148,8 @@ def test_galore_baseline_trains():
                        min_dim_for_lowrank=64, weight_decay=0.0,
                        schedule="constant")
     from repro.models import lm
-    params = lm.init_params(cfg, jax.random.key(0))
-    state = galore.init(params, tcfg, jax.random.key(1))
+    params, state = galore.init(lm.init_params(cfg, jax.random.key(0)),
+                                tcfg, jax.random.key(1))
     loader = StatelessLoader("lm", seed=0, batch=8, seq_len=64,
                              vocab=cfg.vocab_size)
     step_refresh = jax.jit(lambda p, s, b: galore.make_train_step(

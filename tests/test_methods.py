@@ -148,7 +148,7 @@ def test_galore_trainer_matches_standalone_bitexact():
 
     # standalone: identical key schedule to Trainer.__init__
     pkey, okey = jax.random.split(jax.random.key(tcfg.seed))
-    gp, state = galore.init_grouped(lm.init_params(CFG, pkey), tcfg, okey)
+    gp, state = galore.init(lm.init_params(CFG, pkey), tcfg, okey)
     mk = galore.make_train_step(CFG, tcfg)
     step_refresh = jax.jit(lambda p, s, b: mk(p, s, b, True))
     step_plain = jax.jit(lambda p, s, b: mk(p, s, b, False))

@@ -182,7 +182,7 @@ def test_kernel_cache_one_compile_per_key_over_3_cycles(monkeypatch):
     monkeypatch.delenv("REPRO_COMPUTE_DTYPE", raising=False)
     tcfg = _ragged_tcfg()
     params = _ragged_params()
-    gp, state = subspace.init_grouped(params, tcfg, jax.random.key(0))
+    gp, state = subspace.init(params, tcfg, jax.random.key(0))
     x1 = jnp.asarray(RNG.normal(size=(7, 36)), jnp.float32)
     x2 = jnp.asarray(RNG.normal(size=(7, 52)), jnp.float32)
 
@@ -269,7 +269,7 @@ def test_rank_packed_adam_matches_xla(rows, r, monkeypatch):
 
 def test_layout_carries_pack_plans():
     tcfg = _ragged_tcfg()
-    state = subspace.init(_ragged_params(), tcfg, jax.random.key(0))
+    _, state = subspace.init(_ragged_params(), tcfg, jax.random.key(0))
     assert len(state.layout.packs) == len(state.layout.groups)
     for spec, plan in zip(state.layout.groups, state.layout.packs):
         rows = len(spec.leaf_idx) * int(

@@ -387,8 +387,8 @@ def test_lion_registered_and_described():
 
 
 def test_lion_in_bench_variant_grids():
-    """memory_table/walltime_table pick lion up with zero consumer edits:
-    their rows come from methods.available() via variants()."""
+    """memory_table picks lion up with zero consumer edits: its rows come
+    from methods.available() via variants()."""
     import importlib.util
     import os
     root = os.path.join(os.path.dirname(os.path.dirname(

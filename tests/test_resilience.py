@@ -392,7 +392,7 @@ def test_walkback_lands_on_legacy_migrated_checkpoint(tmp_path):
     tree = {"w1": jax.random.normal(jax.random.key(0), (128, 128)),
             "w2": jax.random.normal(jax.random.key(1), (128, 128)),
             "bias": jnp.zeros((128,), jnp.float32)}
-    gp, state = subspace.init_grouped(tree, tcfg, jax.random.key(2))
+    gp, state = subspace.init(tree, tcfg, jax.random.key(2))
     wd = str(tmp_path / "legacy")
     ckpt.save(wd, 1, {"params": tree, "opt": state})   # legacy per-leaf
     ckpt.save(wd, 2, {"params": gp, "opt": state})     # native grouped

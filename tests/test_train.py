@@ -31,14 +31,14 @@ def test_subspace_grad_equals_projected_dense_grad():
     verified through the full transformer + chunked-CE stack.  The grouped
     trainable's stacked gradient rows must each equal the member's lift."""
     params = lm.init_params(CFG, jax.random.key(0))
-    state = subspace.init(params, TCFG, jax.random.key(1))
+    gp, state = subspace.init(params, TCFG, jax.random.key(1))
     batch = _loader()(0)
     loss_fn = steps_mod.build_loss_fn(CFG)
 
-    trainable = subspace.trainable_of(params, state)
+    trainable = subspace.trainable_of(gp, state)
 
     def f_sub(t):
-        return loss_fn(subspace.packed_params(params, state, t), batch)
+        return loss_fn(subspace.packed_params(gp, state, t), batch)
 
     grads_b = jax.grad(f_sub)(trainable)
     dense_grads = jax.grad(lambda p: loss_fn(p, batch))(params)
@@ -58,8 +58,8 @@ def test_subspace_grad_equals_projected_dense_grad():
 
 def test_outer_merge_preserves_function():
     """Merging W += V B^T and zeroing B must not change the model output."""
-    params = lm.init_params(CFG, jax.random.key(0))
-    state = subspace.init(params, TCFG, jax.random.key(1))
+    params, state = subspace.init(lm.init_params(CFG, jax.random.key(0)),
+                                  TCFG, jax.random.key(1))
     # take a few inner steps so B != 0
     step = steps_mod.make_train_step(CFG, TCFG)
     batch = _loader()(0)
@@ -81,8 +81,8 @@ def test_outer_merge_preserves_function():
 
 
 def test_outer_resample_changes_projection():
-    params = lm.init_params(CFG, jax.random.key(0))
-    state = subspace.init(params, TCFG, jax.random.key(1))
+    params, state = subspace.init(lm.init_params(CFG, jax.random.key(0)),
+                                  TCFG, jax.random.key(1))
     outer = steps_mod.make_outer_step(CFG, TCFG)
     _, state2 = outer(params, state)
     diffs = [float(jnp.abs(a.proj - b.proj).max())
