@@ -11,7 +11,7 @@ touches JAX):
   train     Trainer + lowrank_adam, Stiefel r=128, seq 256, batch 64
             (16,384 tokens per step), lazy_k 4, 10 inner steps so the
             outer merge+resample runs twice, fp32 state, donation on;
-            then the N-chunked fused backward of the unembedding alone
+            then the K-tiled fused backward of the unembedding alone
   train_q8  the same with int8 moments and bf16 stochastically rounded
             masters set through TrainConfig: 5 steps at lazy_k 2
   serve     Engine with two tenant adapters from an AdapterStore, 4
@@ -371,8 +371,9 @@ def check_kernel(what: str, op: str, shapes, got, ref) -> None:
 
 def check_backward() -> None:
     """The fused backward at the llama-100m unembedding (16,384 rows,
-    640 -> 32256 padded vocab), whose dB does not fit VMEM whole, so the
-    guard splits N into chunks: dx and dB against an fp32 reference."""
+    640 -> 32256 padded vocab), on the blocks the dispatch layer picks
+    from the shape: 32 row blocks gather the 16 MiB resident dB, which
+    the last one writes out; dx and dB against an fp32 reference."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import dispatch
@@ -387,7 +388,9 @@ def check_backward() -> None:
     hi = jax.lax.Precision.HIGHEST
     ref_dx = (jnp.dot(dy, w.T, precision=hi)
               + jnp.dot(jnp.dot(dy, b, precision=hi), v.T, precision=hi))
-    check_kernel(f"fused backward, unembedding, {key[4]} N-chunks",
+    bm, bn, bk = key[3]
+    check_kernel(f"fused backward, unembedding, blocks bm {bm} bk {bk} "
+                 f"bn {bn}",
                  "lowrank_backward", (m, k, n, RANK), (dx, db),
                  (ref_dx, jnp.dot(dy.T, p, precision=hi)))
 

@@ -21,6 +21,24 @@ def dotf(a: jax.Array, b: jax.Array) -> jax.Array:
     return jax.lax.dot(a, b, preferred_element_type=jnp.float32)
 
 
+def _dot_dims(a: jax.Array, b: jax.Array, dims) -> jax.Array:
+    dt = jnp.promote_types(a.dtype, b.dtype)
+    return jax.lax.dot_general(a.astype(dt), b.astype(dt), (dims, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def dot_nt(a: jax.Array, b: jax.Array) -> jax.Array:
+    """a (m, c) @ b (n, c)^T, contracting c on both operands in place (no
+    transposed copy of b), fp32 accumulation as :func:`dotf`."""
+    return _dot_dims(a, b, ((1,), (1,)))
+
+
+def dot_tn(a: jax.Array, b: jax.Array) -> jax.Array:
+    """a (c, m)^T @ b (c, n), contracting c on both operands in place,
+    fp32 accumulation as :func:`dotf`."""
+    return _dot_dims(a, b, ((0,), (0,)))
+
+
 def sr_bf16(x: jax.Array, bits: jax.Array) -> jax.Array:
     """Stochastically round fp32 ``x`` to bf16 using ``bits``.
 

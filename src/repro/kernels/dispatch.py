@@ -22,10 +22,9 @@ multi-device mesh is active (Mosaic kernels cannot be partitioned by the
 compiler), XLA otherwise.  :func:`route_log` records every choice.  The
 VMEM guard uses each operand's REAL itemsize — a bf16 workload has half
 the working set of the same-shape fp32 one and must not be spuriously
-routed to the XLA fallback.  A fused backward whose whole dB would not
-fit is split over N (``_bwd_chunks``) before it falls back.  ``TABLE``
-maps op -> {route -> impl} and is deliberately a plain dict so tests can
-monkeypatch impls to assert the hot path really flows through here.
+routed to the XLA fallback.  ``TABLE`` maps op -> {route -> impl} and is
+deliberately a plain dict so tests can monkeypatch impls to assert the hot
+path really flows through here.
 
 Attention: ``route("attention", shapes=(B, S, Hq, Hkv, D), ...)`` is
 called by ``models/attention.py::blockwise_attention``, which decides
@@ -45,15 +44,15 @@ Mixed-precision contract (mirrored by kernels/ref.py):
 
 Tiling: the padded dims are the same for every op (M to a multiple of 16
 up to 128 and of 128 beyond, N and K to multiples of 128).  The fused
-forward's blocks follow from the shape and the dtypes alone
-(:func:`_fwd_blocks`): the largest bm (a multiple of 16), bn and bk
-(multiples of 128) that divide the padded dims, stay under
-``FWD_MAX_BLOCKS`` and whose double-buffered working set fits the VMEM
-budget — the fewest grid steps, and on a tie the wider bn/bk.  At the
-12B widths that is 512 x 1024 x 1024; small shapes take their whole padded
-dims.  The route guard sizes exactly those blocks.  The backward takes
-128-blocks over M and N (:func:`_blocks`), the merge and the projection
-256-blocks.
+forward's and backward's blocks follow from the shape and the dtypes alone
+(:func:`_pick_blocks`, shared by :func:`_fwd_blocks` and
+:func:`_bwd_blocks`): the largest bm (a multiple of 16), bn and bk
+(multiples of 128) that divide the padded dims, stay under ``MAX_BLOCKS``
+and whose double-buffered working set fits the VMEM budget — the fewest
+grid steps, and on a tie the wider bn/bk.  At the 12B widths both take
+512 x 1024 x 1024; small shapes take their whole padded dims.  The route guard sizes exactly those
+blocks; the backward's resident dB comes on top (``BWD_DB_VMEM``).  The
+merge and the projection take 256-blocks.
 
 Kernel cache: every Pallas launch is built once per
 ``(op, padded shape, dtypes, blocks, statics)`` key and memoised in
@@ -93,7 +92,7 @@ Array = jax.Array
 
 LANE = 128           # TPU lane count: minor-dim tiling granularity
 SUBLANE = 16         # sublane granularity (16 covers bf16; 8 would do f32)
-VMEM_BUDGET = 12 * 2 ** 20   # conservative slice of the ~16 MB/core VMEM
+VMEM_BUDGET = 12 * 2 ** 20   # the blocks' slice of the 16 MiB scoped VMEM
 
 
 def _interpret() -> bool:
@@ -156,36 +155,6 @@ def _sizes(dtypes: Sequence, n: int, itemsize: int) -> Tuple[float, ...]:
     return (float(itemsize),) * n
 
 
-def _bwd_vmem_bytes(M: int, K: int, N: int, r: int, sizes,
-                    n_chunks: int = 1) -> int:
-    """Working set of the fused backward (see lowrank_backward.py).
-
-    Per-operand itemsizes: (dy, w, v, b, p) — dx rides dy's dtype (fp32
-    partials when N is split into ``n_chunks``), the dx accumulator and
-    the resident dB chunk stay fp32 in VMEM.  Every pipelined block, the
-    outputs included, is double-buffered; only the scratch is single.
-    """
-    sdy, sw, sv, sb, sp = sizes
-    bm, Mp, bn, Np, _, Kp = _blocks(M, N, K)
-    nc = _round_up(-(-Np // n_chunks), bn)     # dB rows resident at once
-    sdx = sdy if n_chunks == 1 else 4
-    blocks = (Kp * bn * sw + Kp * r * sv    # w column strip + v
-              + bm * bn * sdy + bn * r * sb + bm * r * sp  # dy/b/p tiles
-              + bm * Kp * sdx               # dx output block
-              + 4 * nc * r)                 # dB chunk (fp32)
-    return 2 * blocks + 4 * bm * Kp         # + dx f32 accumulator
-
-
-def _bwd_chunks(M: int, K: int, N: int, r: int, sizes) -> Optional[int]:
-    """Fewest N-chunks whose fused-backward working set fits
-    ``VMEM_BUDGET``; None when not even one lane-wide chunk fits."""
-    n_tiles = _blocks(M, N, K)[3] // LANE if N > LANE else 1
-    for c in range(1, n_tiles + 1):
-        if _bwd_vmem_bytes(M, K, N, r, sizes, c) <= VMEM_BUDGET:
-            return c
-    return None
-
-
 def _fwd_tile_bytes(bm: int, bn: int, bk: int, r: int, sizes) -> int:
     """Working set of one forward grid step at blocks (bm, bn, bk).
 
@@ -197,10 +166,29 @@ def _fwd_tile_bytes(bm: int, bn: int, bk: int, r: int, sizes) -> int:
     return 2 * blocks + 4 * (bm * bn + bm * r)   # + f32 acc/accp scratch
 
 
-# largest forward blocks (bm, bn, bk): on a v5e sweep at the 12B widths
-# (PERF.md) they ran at 85-90% of the bf16 peak, the fastest block that
-# fits VMEM_BUDGET or within 0.4% of it
-FWD_MAX_BLOCKS = (512, 1024, 1024)
+def _bwd_tile_bytes(bm: int, bn: int, bk: int, r: int, sizes) -> int:
+    """Working set of one fused-backward grid step at blocks (bm, bn, bk):
+    bn blocks N (the contraction of dx) and bk blocks K (dx's columns).
+
+    Per-operand itemsizes: (dy, w, v, b, p) — the dx tile rides dy's
+    dtype, the dx accumulator and q are fp32.  Pipelined blocks are
+    double-buffered, the scratch is single.  The resident dB (4 N r
+    bytes) is not a block: :data:`BWD_DB_VMEM` bounds it."""
+    sdy, sw, sv, sb, sp = sizes
+    blocks = (bm * bn * sdy + bk * bn * sw + bk * r * sv + bn * r * sb
+              + bm * r * sp
+              + bm * bk * sdy)              # + dx output tile (dy.dtype)
+    return 2 * blocks + 4 * (bm * bk + bm * r)   # + f32 acc and q scratch
+
+
+# largest blocks (bm, bn, bk) of the fused forward and backward: on a v5e
+# sweep of the forward at the 12B widths (PERF.md) they ran at 85-90% of
+# the bf16 peak, the fastest block that fits VMEM_BUDGET or within 0.4%
+# of it; every backward shape of those widths reaches them too
+MAX_BLOCKS = (512, 1024, 1024)
+# the fused backward's resident fp32 dB (N, r), asked of Mosaic on top of
+# the scoped VMEM that the blocks share: a v5e holds 128 MiB of VMEM
+BWD_DB_VMEM = 96 * 2 ** 20
 
 
 def _divisors(n: int, step: int, cap: int) -> list:
@@ -210,25 +198,25 @@ def _divisors(n: int, step: int, cap: int) -> list:
             if b % step == 0 and n % b == 0]
 
 
-def _fwd_blocks(M: int, K: int, N: int, r: int, sizes):
-    """(bm, Mp, bn, Np, bk, Kp) of the fused forward.
+def _pick_blocks(M: int, K: int, N: int, tile_bytes):
+    """(bm, Mp, bn, Np, bk, Kp) of a K-tiled low-rank kernel.
 
     The padded dims are :func:`_blocks`' (sublane 16 for M, lane 128 for
     N and K), the same as the other ops'.  Within them the blocks are the
     largest ``bm`` (a multiple of 16), ``bn`` and ``bk`` (multiples of
-    128) that divide the padded dims, stay under ``FWD_MAX_BLOCKS`` and
-    keep :func:`_fwd_tile_bytes` inside ``VMEM_BUDGET``: fewest grid
-    steps first, and on a tie the wider ``bn``/``bk``.  A shape that fits
-    no candidate keeps the 128-blocks of :func:`_blocks`, and the route
+    128) that divide the padded dims, stay under ``MAX_BLOCKS`` and keep
+    ``tile_bytes(bm, bn, bk)`` inside ``VMEM_BUDGET``: fewest grid steps
+    first, and on a tie the wider ``bn``/``bk``.  A shape that fits no
+    candidate keeps the 128-blocks of :func:`_blocks`, and the route
     guard sends it to XLA.
     """
     bm, Mp, bn, Np, bk, Kp = _blocks(M, N, K)
-    cm, cn, ck = FWD_MAX_BLOCKS
+    cm, cn, ck = MAX_BLOCKS
     best = None
     for tn in _divisors(Np, LANE, cn):
         for tk in _divisors(Kp, LANE, ck):
             for tm in _divisors(Mp, SUBLANE, cm):
-                if _fwd_tile_bytes(tm, tn, tk, r, sizes) > VMEM_BUDGET:
+                if tile_bytes(tm, tn, tk) > VMEM_BUDGET:
                     continue
                 key = ((Mp // tm) * (Np // tn) * (Kp // tk), -min(tn, tk))
                 if best is None or key < best[0]:
@@ -239,11 +227,32 @@ def _fwd_blocks(M: int, K: int, N: int, r: int, sizes):
     return bm, Mp, bn, Np, bk, Kp
 
 
+def _fwd_blocks(M: int, K: int, N: int, r: int, sizes):
+    """(bm, Mp, bn, Np, bk, Kp) of the fused forward; bk blocks the
+    contraction K."""
+    return _pick_blocks(
+        M, K, N, lambda bm, bn, bk: _fwd_tile_bytes(bm, bn, bk, r, sizes))
+
+
+def _bwd_blocks(M: int, K: int, N: int, r: int, sizes):
+    """(bm, Mp, bn, Np, bk, Kp) of the fused backward; bn blocks the
+    contraction N of dx."""
+    return _pick_blocks(
+        M, K, N, lambda bm, bn, bk: _bwd_tile_bytes(bm, bn, bk, r, sizes))
+
+
 def _fwd_vmem_bytes(M: int, K: int, N: int, r: int, sizes) -> int:
     """Working set of the fused forward at the blocks that
     :func:`_fwd_blocks` picks for the shape: the kernel that runs."""
     bm, _, bn, _, bk, _ = _fwd_blocks(M, K, N, r, sizes)
     return _fwd_tile_bytes(bm, bn, bk, r, sizes)
+
+
+def _bwd_vmem_bytes(M: int, K: int, N: int, r: int, sizes) -> int:
+    """Working set of the fused backward at the blocks that
+    :func:`_bwd_blocks` picks for the shape: the kernel that runs."""
+    bm, _, bn, _, bk, _ = _bwd_blocks(M, K, N, r, sizes)
+    return _bwd_tile_bytes(bm, bn, bk, r, sizes)
 
 
 _ROUTES: dict = {}
@@ -310,7 +319,8 @@ def _route(op: str, shapes: Tuple[int, ...], dtypes: Sequence,
             return "xla"
     if op == "lowrank_backward" and shapes:
         m, k, n, r = shapes
-        if _bwd_chunks(m, k, n, r, _sizes(dtypes, 5, itemsize)) is None:
+        if _bwd_vmem_bytes(m, k, n, r, _sizes(dtypes, 5, itemsize)) \
+                > VMEM_BUDGET or 4 * _round_up(n, LANE) * r > BWD_DB_VMEM:
             return "xla"
     if op == "lowrank_batch_forward" and shapes:
         m, k, n, r = shapes   # m = per-row tokens (seq), not batch*seq
@@ -449,20 +459,14 @@ def _pallas_forward(x2: Array, w: Array, v: Array, b: Array,
 def _pallas_backward(dy2: Array, w: Array, v: Array, b: Array, p2: Array):
     M, N = dy2.shape
     K, r = w.shape[0], v.shape[1]
-    bm, Mp, bn, _, _, Kp = _blocks(M, N, K)
-    sizes = tuple(_itemsize(a.dtype) for a in (dy2, w, v, b, p2))
-    # the forced-pallas route (interpret mode off the chip) may reach a
-    # shape the guard refuses: split N as finely as the lanes allow
-    chunks = _bwd_chunks(M, K, N, r, sizes) or max(1, -(-N // LANE))
-    Np = _round_up(N, chunks * bn)
+    bm, Mp, bn, Np, bk, Kp = _bwd_blocks(
+        M, K, N, r, tuple(_itemsize(a.dtype) for a in (dy2, w, v, b, p2)))
     itp = _interpret()
     fn = _cached_kernel(
         "lowrank_backward",
-        ((Mp, Kp, Np, r), _dt_names(dy2, w, v, b, p2), (bm, bn), chunks,
-         itp),
+        ((Mp, Kp, Np, r), _dt_names(dy2, w, v, b, p2), (bm, bn, bk), itp),
         lambda: (lambda dyp, wp, vp, bp, pp: _pl_backward(
-            dyp, wp, vp, bp, pp, bm=bm, bn=bn, n_chunks=chunks,
-            interpret=itp)))
+            dyp, wp, vp, bp, pp, bm=bm, bk=bk, bn=bn, interpret=itp)))
     dx, db = fn(_pad2(dy2, Mp, Np), _pad2(w, Kp, Np), _pad2(v, Kp, r),
                 _pad2(b, Np, r), _pad2(p2, Mp, r))
     return dx[:M, :K], db[:N]

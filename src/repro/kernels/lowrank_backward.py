@@ -1,29 +1,31 @@
-"""Pallas TPU kernel: fused low-rank backward — dx and dB in ONE dy pass.
+"""Pallas TPU kernel: fused low-rank backward, dx and dB from one kernel.
 
 The inner-step backward of Algorithm 1 needs
 
     dx = dy W^T + (dy B) V^T        (M, K)
     dB = dy^T p                     (N, r),  p = x V saved by the forward
 
-Unfused, autodiff schedules three independent contractions over dy — dy is
-streamed from HBM three times and (dy B) once more.  This kernel makes one
-pass over dy tiles: grid (M/bm, N/bn) with the FULL K dimension blocked into
-VMEM, so each (bm, bn) dy tile is read exactly once and contributes
+The kernel is a K-tiled matmul dx = dy W^T with the rank-r terms fused
+in.  Grid (M/bm, K/bk, N/bn): each (i, k) tile of dx gathers
+dy_ij w_kj^T over the innermost j axis in a (bm, bk) fp32 accumulator,
+and its epilogue adds q_i v_k^T and writes dx once, in dy's dtype.
 
-  * its j-slice of the dx row-strip accumulator  (dy w_j^T + (dy b_j) v^T),
-  * its i-contribution to dB rows j              (dy^T p_i).
+  * q = dy B (bm, r) does not depend on k: it accumulates in VMEM scratch
+    during the k == 0 sweep of a row of tiles and is reused by every
+    later k (the forward's ``accp``, mirrored).
+  * dB sums dy_ij^T p_i over the row blocks i, the outer axis, so it stays
+    resident: a (N, r) fp32 VMEM scratch gathers it from the dy tiles of
+    each row's k == 0 sweep, and one DMA copies it to HBM when the last
+    row's sweep is done.  Nothing but dx and dB reaches HBM, and dB takes
+    no pipelined block.
 
-dx accumulates in a (bm, K) f32 scratch written at the end of each i row;
-dB lives in VMEM as a resident output block because its contraction dim
-(M) is the OUTER grid axis.  A wide N (an unembedding) would not fit whole,
-so the grid carries a leading chunk axis: grid (C, M/bm, N/(C*bn)), and
-chunk c owns dB rows [c*N/C, (c+1)*N/C) — resident for the whole i sweep
-and written back once when c advances.  Each chunk then yields a partial
-dx over its own N columns; those are emitted fp32 as (C, M, K) and summed
-outside the kernel (C = 1 writes dx straight in dy.dtype).  VMEM cost is
-therefore ~ K*(bn+r)*s + 4*(bm*K + (N/C)*r) bytes — the dispatch layer
-picks C so it fits the budget and falls back to the XLA path when even
-one bn-wide chunk does not.
+Neither a full-K strip of W nor a K-wide dx accumulator has to fit
+VMEM: the blocks need not be square (bm a multiple of 16, bk and bn
+multiples of 128, or the whole dimension), and ``kernels/dispatch.py``
+picks them from the shapes and dtypes so that the double-buffered blocks
+fit its VMEM budget; the resident dB is asked of Mosaic on top of the
+default scoped limit.  Mixed precision as in ``kernels/ref.py``: every
+contraction accumulates fp32, dx carries dy.dtype, dB is fp32.
 """
 from __future__ import annotations
 
@@ -34,79 +36,106 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._mixed import dot_nt as _dot_nt
+from ._mixed import dot_tn as _dot_tn
 from ._mixed import dotf as _dotf
 
 Array = jax.Array
 
+# Mosaic's default scoped VMEM on a v5e: the dispatch layer sizes the
+# pipelined blocks inside it, and the kernel asks for the resident dB on
+# top (the chip holds 128 MiB of VMEM)
+SCOPED_VMEM = 16 * 2 ** 20
 
-def _kernel(dy_ref, w_ref, v_ref, b_ref, p_ref, dx_ref, db_ref, acc_ref, *,
-            n_j: int, bn: int):
-    i = pl.program_id(1)
+
+def _kernel(dy_ref, w_ref, v_ref, b_ref, p_ref, dx_ref, db_hbm, acc_ref,
+            q_ref, dbacc_ref, *, n_i: int, n_j: int, bn: int):
+    i = pl.program_id(0)
+    k = pl.program_id(1)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
-    def _init_acc():
+    def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(jnp.logical_and(i == 0, j == 0))
-    def _init_db():
-        db_ref[...] = jnp.zeros_like(db_ref)
-
     dy = dy_ref[...]                                     # (bm, bn)
-    # dx row-strip: dy w_j^T + (dy b_j) v^T, f32 accumulate over j
-    q = _dotf(dy, b_ref[...])                            # (bm, r)
-    acc_ref[...] += (
-        _dotf(dy, w_ref[...].T) +
-        _dotf(q, v_ref[...].T.astype(jnp.float32)))
-    # dB rows for this j block: accumulate dy^T p across the i sweep
-    db_ref[pl.ds(j * bn, bn), :] += _dotf(dy.T, p_ref[...].astype(dy.dtype))
+    acc_ref[...] += _dot_nt(dy, w_ref[...])              # dy w_kj^T
+
+    # q = dy B and dB read the same dy tiles for every k: take them from
+    # the k == 0 sweep only
+    @pl.when(k == 0)
+    def _sweep0():
+        @pl.when(j == 0)
+        def _init_q():
+            q_ref[...] = jnp.zeros_like(q_ref)
+
+        q_ref[...] += _dotf(dy, b_ref[...])
+        part = _dot_tn(dy, p_ref[...])                   # dy_ij^T p_i
+        rows = pl.ds(pl.multiple_of(j * bn, bn), bn)
+
+        @pl.when(i == 0)
+        def _first():
+            dbacc_ref[rows, :] = part
+
+        @pl.when(i > 0)
+        def _more():
+            dbacc_ref[rows, :] += part
+
+    # the last row's k == 0 sweep completes dB: one copy to HBM
+    @pl.when((i == n_i - 1) & (k == 0) & (j == n_j - 1))
+    def _out():
+        pltpu.sync_copy(dbacc_ref, db_hbm)
 
     @pl.when(j == n_j - 1)
     def _fin():
-        dx_ref[...] = acc_ref[...].astype(dx_ref.dtype)
+        dx_ref[...] = (acc_ref[...] + _dot_nt(q_ref[...], v_ref[...])
+                       ).astype(dx_ref.dtype)
 
 
 def lowrank_backward(dy: Array, w: Array, v: Array, b: Array, p: Array, *,
-                     bm: int = 128, bn: int = 128, n_chunks: int = 1,
+                     bm: int = 128, bk: int = 128, bn: int = 128,
                      interpret: bool = False):
     """dy (M,N), w (K,N), v (K,r), b (N,r), p (M,r) -> (dx (M,K), db (N,r)).
 
-    db is fp32 (Adam consumes it in fp32); dx is dy.dtype.  ``n_chunks``
-    splits N so that only N/n_chunks rows of dB are resident at a time.
+    db is fp32 (Adam consumes it in fp32); dx is dy.dtype.
     """
     M, N = dy.shape
     K = w.shape[0]
     r = v.shape[1]
-    bm, bn = min(bm, M), min(bn, N // n_chunks)
-    assert M % bm == 0 and N % (n_chunks * bn) == 0, (M, N, bm, bn,
-                                                       n_chunks)
-    n_j = N // (n_chunks * bn)
-    nc = N // n_chunks
-    dx_dtype = dy.dtype if n_chunks == 1 else jnp.float32
+    bm, bk, bn = min(bm, M), min(bk, K), min(bn, N)
+    assert M % bm == 0 and K % bk == 0 and N % bn == 0, (M, K, N, bm, bk, bn)
+    n_i, n_j = M // bm, N // bn
 
-    grid = (n_chunks, M // bm, n_j)
-    dx, db = pl.pallas_call(
-        functools.partial(_kernel, n_j=n_j, bn=bn),
-        grid=grid,
+    def swept(k, j):
+        # past the k == 0 sweep b is not read: hold its block index at the
+        # sweep's last one, so that the pipeline fetches it no more
+        return jnp.where(k == 0, j, n_j - 1)
+
+    return pl.pallas_call(
+        functools.partial(_kernel, n_i=n_i, n_j=n_j, bn=bn),
+        grid=(n_i, K // bk, n_j),
         in_specs=[
-            pl.BlockSpec((bm, bn), lambda c, i, j: (i, c * n_j + j)),
-            pl.BlockSpec((K, bn), lambda c, i, j: (0, c * n_j + j)),
-            pl.BlockSpec((K, r), lambda c, i, j: (0, 0)),
-            pl.BlockSpec((bn, r), lambda c, i, j: (c * n_j + j, 0)),
-            pl.BlockSpec((bm, r), lambda c, i, j: (i, 0)),
+            pl.BlockSpec((bm, bn), lambda i, k, j: (i, j)),
+            pl.BlockSpec((bk, bn), lambda i, k, j: (k, j)),
+            pl.BlockSpec((bk, r), lambda i, k, j: (k, 0)),
+            pl.BlockSpec((bn, r), lambda i, k, j: (swept(k, j), 0)),
+            pl.BlockSpec((bm, r), lambda i, k, j: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((None, bm, K), lambda c, i, j: (c, i, 0)),
-            pl.BlockSpec((nc, r), lambda c, i, j: (c, 0)),
+            pl.BlockSpec((bm, bk), lambda i, k, j: (i, k)),
+            pl.BlockSpec(memory_space=pl.ANY),      # dB, copied once
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_chunks, M, K), dx_dtype),
+            jax.ShapeDtypeStruct((M, K), dy.dtype),
             jax.ShapeDtypeStruct((N, r), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((bm, K), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32),
+                        pltpu.VMEM((bm, r), jnp.float32),
+                        pltpu.VMEM((N, r), jnp.float32)],
+        # every axis carries state: i the resident dB, k q, j acc
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=SCOPED_VMEM + 4 * N * r),
         interpret=interpret,
         name="lowrank_backward",
     )(dy, w, v, b, p)
-    if n_chunks == 1:
-        return dx[0], db
-    return jnp.sum(dx, axis=0).astype(dy.dtype), db

@@ -29,18 +29,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._mixed import dot_nt as _dot_nt
 from ._mixed import dotf as _dotf
 
 Array = jax.Array
-
-
-def _dot_nt(a: Array, b: Array) -> Array:
-    """a (m, r) @ b (n, r)^T, contracting r on both operands in place (no
-    transposed copy of b), fp32 accumulation as :func:`_dotf`."""
-    dt = jnp.promote_types(a.dtype, b.dtype)
-    return jax.lax.dot_general(a.astype(dt), b.astype(dt),
-                               (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
 
 
 def _kernel(x_ref, w_ref, v_ref, b_ref, o_ref, acc_ref, accp_ref, *,

@@ -15,6 +15,7 @@ import pytest
 
 from repro.configs import TrainConfig
 from repro.kernels import dispatch, ref
+from repro.kernels.lowrank_backward import lowrank_backward as pl_backward
 from repro.kernels.lowrank_forward import lowrank_forward as pl_forward
 from repro.models.linear import lowrank_matmul
 from repro.optim import subspace
@@ -142,25 +143,114 @@ def test_backward_matches_ref(m, k, n, r, route, monkeypatch):
 
 @pytest.mark.parametrize("m,k,n,r", [(33, 130, 650, 5), (64, 128, 384, 16)])
 def test_backward_n_chunked_matches_ref(m, k, n, r, monkeypatch):
-    # a budget that holds only part of dB forces the N-chunked kernel
-    # (partial dx summed in fp32 outside the kernel)
+    # a budget that holds only the smallest blocks splits N (and K, and
+    # M where it can) into several blocks: dx gathered over N blocks, q
+    # reused across K blocks, the resident dB gathered over the row blocks
     monkeypatch.setenv("REPRO_KERNEL_DISPATCH", "pallas")
     sizes = (4,) * 5
     monkeypatch.setattr(dispatch, "VMEM_BUDGET",
-                        dispatch._bwd_vmem_bytes(m, k, n, r, sizes, 2))
-    assert dispatch._bwd_chunks(m, k, n, r, sizes) == 2
+                        dispatch._bwd_tile_bytes(16, 128, 128, r, sizes))
+    bm, mp, bn, np_, bk, kp = dispatch._bwd_blocks(m, k, n, r, sizes)
+    assert (bm, bn, bk) == (16, 128, 128) and np_ // bn >= 3
     dispatch.clear_kernel_cache()
     _, w, v, b = _ops(m, k, n, r)
     dy, p = _arr((m, n)), _arr((m, r))
     dx, db = dispatch.lowrank_backward(dy, w, v, b, p)
     (key,) = dispatch.kernel_cache_info()["keys"]
-    assert key[0] == "lowrank_backward" and key[4] == 2
+    assert key[0] == "lowrank_backward" and key[3] == (16, 128, 128)
     np.testing.assert_allclose(
         np.asarray(dx), np.asarray(dy @ w.T + (dy @ b) @ v.T),
         rtol=2e-4, atol=5e-3)
     np.testing.assert_allclose(np.asarray(db),
                                np.asarray(dy).T @ np.asarray(p),
                                rtol=2e-4, atol=5e-3)
+
+
+# several blocks on every grid axis: the dx accumulator carried over the
+# N blocks, q from the k == 0 sweep reused by later K blocks, b held past
+# that sweep, the resident dB gathered over the row blocks and copied out
+# after the last; and the edge cases of one K block, of one N block and of
+# one row block
+@pytest.mark.parametrize("m,k,n,r,blocks", [
+    (96, 512, 768, 16, (48, 256, 128)),      # grid (2, 2, 6)
+    (32, 768, 256, 8, (16, 128, 128)),       # grid (2, 6, 2)
+    (48, 384, 384, 5, (16, 128, 384)),       # grid (3, 3, 1)
+    (48, 128, 512, 4, (16, 128, 256)),       # grid (3, 1, 2)
+    (16, 256, 640, 8, (16, 128, 128)),       # grid (1, 2, 5)
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_backward_kernel_non_square_blocks_match_ref(m, k, n, r, blocks,
+                                                     dtype):
+    bm, bk, bn = blocks
+    x, w, v, b = _ops(m, k, n, r, dtype)
+    dy = _arr((m, n), dtype)
+    p = (x @ v).astype(dtype)               # the forward's residual
+    dx, db = pl_backward(dy, w, v, b, p, bm=bm, bk=bk, bn=bn,
+                         interpret=True)
+    assert dx.dtype == dtype and db.dtype == jnp.float32
+    assert dx.shape == (m, k) and db.shape == (n, r)
+    # the VJP of the reference forward, in fp32
+    f32 = [a.astype(jnp.float32) for a in (x, w, v, b, dy)]
+    _, vjp = jax.vjp(lambda x_, b_: ref.lowrank_forward(x_, f32[1], f32[2],
+                                                        b_), f32[0], f32[3])
+    want_dx, want_db = vjp(f32[4])
+    rel = 1e-5 if dtype == jnp.float32 else 1e-2
+    for got, want in ((dx, want_dx), (db, want_db)):
+        want = np.asarray(want)
+        np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                                   rtol=0, atol=rel * np.abs(want).max())
+
+
+# (M, K, N, r) -> the backward's (bm, bn, bk), bn blocking the
+# contraction N of dx: the NeMo-12B q, k/v, o, gate/up, down and
+# unembedding chunk; qwen2-7b's down-projection; llama-100m's unembedding
+# chunk; a decode row block and a ragged shape
+BWD_PICKS = {
+    (8192, 5120, 4096, 128): (512, 1024, 1024),
+    (8192, 5120, 1024, 128): (512, 1024, 1024),
+    (8192, 4096, 5120, 128): (512, 1024, 1024),
+    (8192, 5120, 14336, 128): (512, 1024, 1024),
+    (8192, 14336, 5120, 128): (512, 1024, 1024),
+    (1024, 5120, 16384, 128): (512, 1024, 1024),
+    (4096, 18944, 3584, 128): (512, 896, 512),
+    (16384, 640, 32256, 128): (512, 896, 640),
+    (16, 5120, 14336, 128): (16, 1024, 1024),
+    (33, 130, 650, 5): (48, 768, 256),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(BWD_PICKS))
+def test_backward_blocks_fit_the_padded_shape(shape, monkeypatch):
+    m, k, n, r = shape
+    sizes = (2.0,) * 5      # bf16 operands
+    bm, mp, bn, np_, bk, kp = dispatch._bwd_blocks(m, k, n, r, sizes)
+    assert (bm, bn, bk) == BWD_PICKS[shape]
+    _, mp0, _, np0, _, kp0 = dispatch._blocks(m, n, k)
+    assert (mp, np_, kp) == (mp0, np0, kp0)     # N 5120 stays 5120
+    assert mp % bm == 0 and np_ % bn == 0 and kp % bk == 0
+    assert bm % dispatch.SUBLANE == 0
+    assert bn % dispatch.LANE == 0 and bk % dispatch.LANE == 0
+    assert dispatch._bwd_vmem_bytes(m, k, n, r, sizes) \
+        <= dispatch.VMEM_BUDGET
+    monkeypatch.delenv("REPRO_KERNEL_DISPATCH", raising=False)
+    monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
+    assert dispatch.route("lowrank_backward", shapes=shape,
+                          dtypes=(jnp.bfloat16,) * 5) == "pallas"
+
+
+@pytest.mark.parametrize("n,route", [(152064, "pallas"), (262144, "xla")])
+def test_backward_resident_db_bounds_the_width(n, route, monkeypatch):
+    # every block fits at any width; what grows with N is the resident
+    # fp32 dB (N, r): qwen2-7b's whole vocabulary (74.25 MiB) keeps the
+    # kernel, a dB over BWD_DB_VMEM takes XLA
+    monkeypatch.delenv("REPRO_KERNEL_DISPATCH", raising=False)
+    monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
+    shape = (1024, 3584, n, 128)
+    assert dispatch._bwd_vmem_bytes(*shape, (2.0,) * 5) \
+        <= dispatch.VMEM_BUDGET
+    assert (4 * n * 128 <= dispatch.BWD_DB_VMEM) == (route == "pallas")
+    assert dispatch.route("lowrank_backward", shapes=shape,
+                          dtypes=(jnp.bfloat16,) * 5) == route
 
 
 def test_route_log_records_each_choice(monkeypatch):

@@ -286,10 +286,15 @@ def test_vmem_guard_uses_real_itemsize(monkeypatch):
     monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
     shapes = (256, 6144, 2048, 32)   # (M, K, N, r)
     m, k, n, r = shapes
+    # the fused backward tiles every dim, so only a budget below its
+    # smallest blocks' working set refuses it: set one that holds the bf16
+    # working set of those blocks but not the fp32 one
+    monkeypatch.setattr(dispatch, "VMEM_BUDGET", dispatch._bwd_tile_bytes(
+        16, 128, 128, r, (2,) * 5))
     f32 = dispatch._bwd_vmem_bytes(m, k, n, r, (4,) * 5)
     bf16 = dispatch._bwd_vmem_bytes(m, k, n, r, (2,) * 5)
-    # the shape is chosen to straddle the budget — keep it meaningful
-    assert bf16 < dispatch.VMEM_BUDGET < f32
+    # the budget is chosen to straddle the two — keep it meaningful
+    assert bf16 <= dispatch.VMEM_BUDGET < f32
     assert dispatch.route("lowrank_backward", shapes=shapes,
                           dtypes=("float32",) * 5) == "xla"
     assert dispatch.route("lowrank_backward", shapes=shapes,

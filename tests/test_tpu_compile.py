@@ -6,9 +6,9 @@ working set larger than the kernel's scoped VMEM.  Here every kernel the
 training and serving paths reach is compiled (not run) by the TPU compiler
 for one chip of a described ``v5e:2x2`` topology, at the padded shapes the
 dispatch layer hands it for llama-100m (12 x d640 / ff1712, vocab 32128,
-r=128, 64 x 256 tokens per step), at the widest qwen2-7b shape the VMEM
-guard admits and, for the forward, at NeMo-12B's widths (d5120 / ff14336,
-2 x 4096 tokens per step), each at the blocks the dispatch layer picks.
+r=128, 64 x 256 tokens per step), at qwen2-7b's widest shapes and at
+NeMo-12B's widths (d5120 / ff14336, 2 x 4096 tokens per step), each at the
+blocks the dispatch layer picks.
 Each case asserts that the program holds a ``tpu_custom_call`` — the
 compiled kernel, not an XLA fallback — named after the kernel's public
 function, so that a profiler trace tells the kernels apart by name.
@@ -137,35 +137,43 @@ BWD = {
     "llama100m_down": (TOKENS, 1792, 640),
     "llama100m_unembed": (TOKENS, 640, 32256),
     "qwen2_7b_up": (4096, 3584, 18944),
+    "qwen2_7b_down": (4096, 18944, 3584),
+    "nemo12b_o": (NEMO_TOKENS, 4096, 5120),
+    "nemo12b_gate_up": (NEMO_TOKENS, 5120, 14336),
+    "nemo12b_down": (NEMO_TOKENS, 14336, 5120),
 }
 
 
 @pytest.mark.parametrize("case", sorted(BWD))
 def test_lowrank_backward_compiles(case, one_chip, monkeypatch):
     m, k, n = BWD[case]
-    # the guard admits the shape on the chip, with the N-chunking it
+    # the guard admits the shape on the chip at the blocks the rule
     # picks; the compiler must then accept exactly that kernel
     monkeypatch.delenv("REPRO_KERNEL_DISPATCH", raising=False)
     monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
     assert dispatch.route("lowrank_backward", shapes=(m, k, n, RANK),
                           dtypes=(BF16,) * 5) == "pallas"
-    chunks = dispatch._bwd_chunks(m, k, n, RANK, (2,) * 5)
-    n = dispatch._round_up(n, chunks * 128)
     monkeypatch.undo()
+    bm, m, bn, n, bk, k = dispatch._bwd_blocks(m, k, n, RANK, (2,) * 5)
     hlo = _compile(lambda dy, w, v, b, p: lowrank_backward(
-        dy, w, v, b, p, n_chunks=chunks), one_chip,
+        dy, w, v, b, p, bm=bm, bk=bk, bn=bn), one_chip,
         ((m, n), BF16), ((k, n), BF16), ((k, RANK), BF16),
         ((n, RANK), BF16), ((m, RANK), BF16))
     _assert_kernel(hlo, "lowrank_backward")
 
 
 def test_lowrank_backward_refuses_what_cannot_fit(monkeypatch):
-    # qwen2-7b's down-projection: even a 128-wide N chunk leaves the
-    # 18944-wide dx strip over budget, so the guard keeps it on XLA
+    # the fused backward tiles K and N, so every width fits (qwen2-7b's
+    # down-projection compiles above); what it refuses is a budget below
+    # the working set of its smallest blocks
     monkeypatch.delenv("REPRO_KERNEL_DISPATCH", raising=False)
     monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
-    assert dispatch.route("lowrank_backward",
-                          shapes=(4096, 18944, 3584, RANK),
+    shape = (4096, 18944, 3584, RANK)
+    assert dispatch.route("lowrank_backward", shapes=shape,
+                          dtypes=(BF16,) * 5) == "pallas"
+    monkeypatch.setattr(dispatch, "VMEM_BUDGET", dispatch._bwd_tile_bytes(
+        16, 128, 128, RANK, (2,) * 5) - 1)
+    assert dispatch.route("lowrank_backward", shapes=shape,
                           dtypes=(BF16,) * 5) == "xla"
 
 
